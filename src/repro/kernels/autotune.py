@@ -31,8 +31,9 @@ Two search modes:
     the two grid orders) are resolved by measurement in this mode.
 
 Candidates are pruned by a VMEM working-set bound (accumulator + double-
-buffered input/output blocks must fit), so an "auto" launch never exceeds
-the hardware even at extreme shapes.
+buffered input/output blocks + epilogue temporaries must fit half the
+kernels' scoped-VMEM limit), so an "auto" launch compiles even at
+extreme shapes.
 """
 from __future__ import annotations
 
@@ -43,6 +44,7 @@ import threading
 from typing import Callable, Optional
 
 from .perf_model import estimate_rbgp4mm_dims
+from .rbgp4mm import VMEM_LIMIT_BYTES
 
 __all__ = [
     "TuneResult",
@@ -59,9 +61,11 @@ __all__ = [
 # Token-tile widths considered (clipped by n and the VMEM bound).
 BLOCK_N_CANDIDATES = (128, 256, 512, 1024, 2048)
 GRID_ORDERS = ("nm", "mn")
-# Conservative per-core VMEM working-set budget: accumulator (f32) +
-# double-buffered x/w/out blocks.
-VMEM_BUDGET_BYTES = 16 * 2 ** 20
+# The buffers working_set_bytes counts may fill half the kernels' scoped
+# VMEM limit.  Compiling each kernel the model runs at tinyllama-1.1b
+# widths for a described v5e (n=1024, block_n 512/1024, f32/bf16/int8)
+# put the compiler's whole allocation at 1.1x-1.9x of this count.
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES // 2
 MEASURE_REPS = 5
 
 _DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "float64": 8,
@@ -245,27 +249,40 @@ def _key(kind: str, dims, n_bucket: int, dtype: str, platform: str,
     )
 
 
+def working_set_bytes(dims, bn: int, kind: str, el: int, w_el: int) -> int:
+    """VMEM bytes of the buffers one grid step of a ``kind`` kernel holds.
+
+    The pipeline double-buffers every input and output block.  The
+    ``"rhs"`` count is that of its largest variant (fused activation with
+    a pre-activation output and a residual input), so one cache entry is
+    valid for every epilogue.
+    """
+    tile_out = bn * dims.tile_m                  # (BN, TM) output tile
+    x_blk = 2 * bn * dims.tile_k * el            # gathered input tile
+    w_tile = dims.tile_m * dims.d_i * dims.chunk_cols
+    if "sddmm" in kind:
+        # f32 accumulator and dW tile (TM, d_i*C); cotangent (BN, TM) in
+        return w_tile * (4 + 2 * el) + 2 * tile_out * el + x_blk
+    ws = tile_out * (4 + 2 * el) + x_blk + 2 * w_tile * w_el  # acc, y, x, W
+    if w_el < el:
+        ws += w_tile * 4                         # f32 upcast of int8 values
+    if kind == "rhs":
+        # pre-activation output, residual input, f32 epilogue temporaries
+        ws += tile_out * (2 * el + 2 * el + 2 * 4)
+    return ws
+
+
 def candidate_block_ns(dims, n: int, dtype: str,
-                       value_dtype: Optional[str] = None) -> list[int]:
+                       value_dtype: Optional[str] = None,
+                       kind: str = "rhs") -> list[int]:
     """Feasible block_n values: <= padded n, within the VMEM budget."""
     el = _DTYPE_BYTES.get(dtype, 4)
     w_el = _DTYPE_BYTES.get(value_dtype or dtype, 4)
-    dcols = dims.d_i * dims.chunk_cols
-    out = []
-    for bn in BLOCK_N_CANDIDATES:
-        if bn > max(_n_bucket(n), BLOCK_N_CANDIDATES[0]):
-            break
-        working_set = (
-            bn * dims.tile_m * 4                      # f32 accumulator
-            + 2 * bn * dims.tile_k * el               # x block, double-buffered
-            + 2 * dims.tile_m * dims.d_o * dcols * w_el  # w row strip
-            + 2 * bn * dims.tile_m * el               # out block
-        )
-        if working_set <= VMEM_BUDGET_BYTES:
-            out.append(bn)
-    if not out:
-        out = [BLOCK_N_CANDIDATES[0]]
-    return out
+    out = [bn for bn in BLOCK_N_CANDIDATES
+           if bn <= max(_n_bucket(n), BLOCK_N_CANDIDATES[0])
+           and working_set_bytes(dims, bn, kind, el, w_el)
+           <= VMEM_BUDGET_BYTES]
+    return out or [BLOCK_N_CANDIDATES[0]]
 
 
 def _search_model(dims, n: int, dtype: str, kind: str,
@@ -279,7 +296,7 @@ def _search_model(dims, n: int, dtype: str, kind: str,
     """
     el = _DTYPE_BYTES.get(dtype, 4)
     w_el = _DTYPE_BYTES.get(value_dtype or dtype, 4)
-    cands = candidate_block_ns(dims, n, dtype, value_dtype)
+    cands = candidate_block_ns(dims, n, dtype, value_dtype, kind)
     if "sddmm" in kind:
         # the reduction runs over n: per-candidate traffic is bn-invariant,
         # so take the largest feasible tile (fewest grid steps)
@@ -333,7 +350,7 @@ def _search_measured(dims, n: int, dtype: str, kind: str,
     adj = jnp.asarray(adj_o)
     best = None
     for order in (GRID_ORDERS if kind == "rhs" else ("nm",)):
-        for bn in candidate_block_ns(dims, n, dtype, value_dtype):
+        for bn in candidate_block_ns(dims, n, dtype, value_dtype, kind):
             if kind == "rhs":
                 fn = jax.jit(lambda x, w, _bn=bn, _o=order: K.rbgp4mm_rhs(
                     dims, adj, x, w, scales=scales, block_n=_bn,
@@ -362,18 +379,25 @@ def _search_measured(dims, n: int, dtype: str, kind: str,
                     dims, adj, g, x, block_n=_bn))
             try:
                 jax.block_until_ready(fn(x, w))  # compile + warm
-                ts = []
-                for _ in range(MEASURE_REPS):
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(fn(x, w))
-                    ts.append(time.perf_counter() - t0)
-                us = sorted(ts)[len(ts) // 2] * 1e6
-            except Exception:
+            except jax.errors.JaxRuntimeError as e:
+                # the one failure that prunes a candidate: the compiler
+                # refusing the tile for its memory; anything else is a bug
+                if "RESOURCE_EXHAUSTED" not in str(e):
+                    raise
                 continue
+            ts = []
+            for _ in range(MEASURE_REPS):
+                t0 = time.perf_counter()
+                jax.block_until_ready(fn(x, w))
+                ts.append(time.perf_counter() - t0)
+            us = sorted(ts)[len(ts) // 2] * 1e6
             if best is None or us < best.us_estimate:
                 best = TuneResult(bn, order, us, "measured")
-    return best if best is not None else _search_model(dims, n, dtype, kind,
-                                                       value_dtype)
+    if best is None:
+        raise RuntimeError(
+            f"no block_n candidate of {kind} kernel {dims} compiles at "
+            f"n={n} {dtype}")
+    return best
 
 
 def autotune(dims, n: int, *, dtype: str = "float32", kind: str = "rhs",
@@ -416,7 +440,7 @@ def autotune(dims, n: int, *, dtype: str = "float32", kind: str = "rhs",
         # a bad launch (block_n=0 would divide-by-zero deep in a forward)
         if (hit.grid_order in GRID_ORDERS
                 and hit.block_n in candidate_block_ns(dims, nb, dtype,
-                                                      value_dtype)):
+                                                      value_dtype, kind)):
             _notify(kind, dims, nb, dtype, value_dtype, platform, hit,
                     cached=True)
             return hit

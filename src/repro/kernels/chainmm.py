@@ -66,7 +66,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .rbgp4mm import _CompilerParams, _round_up
+from .rbgp4mm import _dequant_row, _params, _round_up, _scales_by_slot
 
 __all__ = [
     "ChainDims",
@@ -258,14 +258,12 @@ def _chain_rhs_accumulate(dims: ChainDims, x, w, acc_ref, scales=None) -> None:
     """
     G, C = dims.leaf_rows, dims.leaf_cols
     full = dims.full_col_starts
+    if scales is not None:
+        w = w.astype(jnp.float32)  # int8 rows pack 32 per vreg; slice in f32
     for row_off, col_starts in dims.row_groups:
         w_u = w[row_off:row_off + G, :]  # (G, inner)
         if scales is not None:
-            s_u = scales[row_off // G, :]  # (inner/C,) leaf-block scales
-            w_u = (
-                w_u.astype(jnp.float32).reshape(G, dims.inner // C, C)
-                * s_u[None, :, None]
-            ).reshape(G, dims.inner)
+            w_u = _dequant_row(w_u, scales, row_off // G, C).astype(x.dtype)
         if col_starts == full:
             # dense mid structure: the whole X tile, no concat
             x_u = x
@@ -299,7 +297,7 @@ def _chain_rhs_kernel(dims: ChainDims, has_scales: bool, adj_ref, *refs):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     _chain_rhs_accumulate(dims, x_ref[...], w_ref[...], acc_ref,
-                          scales=s_ref[...] if has_scales else None)
+                          scales=s_ref)
 
     @pl.when(kk == dims.d_head - 1)
     def _write():
@@ -361,10 +359,11 @@ def chainmm_rhs(
     operands = [x, w_data.reshape(m, dims.data_cols)]
     if scales is not None:
         in_specs.append(
-            pl.BlockSpec((dims.tile_m // G, dims.inner // C),
-                         lambda i, j, kk, adj: (j, kk))
+            pl.BlockSpec((None, dims.tile_m // G, dims.inner // C),
+                         lambda i, j, kk, adj: (kk, j, 0),
+                         memory_space=pltpu.SMEM)
         )
-        operands.append(scales.astype(jnp.float32))
+        operands.append(_scales_by_slot(scales, dims.d_head))
 
     out = pl.pallas_call(
         functools.partial(_chain_rhs_kernel, dims, scales is not None),
@@ -378,9 +377,7 @@ def chainmm_rhs(
             scratch_shapes=[pltpu.VMEM((bn, dims.tile_m), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((n_pad, m), out_dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(adj_head, *operands)
     return out[:n] if n_pad != n else out
@@ -476,9 +473,7 @@ def chain_sddmm_rhs(
                                        jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, dims.data_cols), out_dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
+        compiler_params=_params("parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
     )(adj_head, g, x)
     return out

@@ -31,6 +31,7 @@ from __future__ import annotations
 import dataclasses
 
 __all__ = [
+    "PEAK_DEVICE_KIND",
     "PEAK_FLOPS",
     "HBM_BW",
     "KernelEstimate",
@@ -42,6 +43,11 @@ __all__ = [
     "estimate_unstructured",
 ]
 
+# Published peaks of one TPU v5e chip (Google Cloud documentation, "TPU
+# v5e"): 197 TFLOP/s bf16, 819 GB/s HBM.  PEAK_DEVICE_KIND is the
+# ``device_kind`` JAX reports for that chip; a time measured on any other
+# device is not comparable with these numbers.
+PEAK_DEVICE_KIND = "TPU v5 lite"
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
 

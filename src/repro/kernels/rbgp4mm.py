@@ -92,8 +92,17 @@ __all__ = [
     "rbgp4_sddmm_rhs_stacked",
 ]
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+# Scoped-VMEM limit handed to the compiler for every kernel: half of a
+# v5e core's 128 MiB, four times the compiler's 16 MiB default.  The
+# autotuner (autotune.VMEM_BUDGET_BYTES) fills half of it with the buffers
+# it counts; the other half is the compiler's own scratch.
+VMEM_LIMIT_BYTES = 64 * 2**20
+
+
+def _params(*semantics: str):
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
 
 # Activations fusable into the kernel epilogue (VPU elementwise on the f32
 # accumulator).  Names intentionally match ``models.mlp.ACTS``.
@@ -295,9 +304,7 @@ def rbgp4mm(
             scratch_shapes=[pltpu.VMEM((dims.tile_m, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n_pad), out_dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(adj_o, w_data.reshape(m, dims.d_o * dcols), x)
     return out[:, :n] if n_pad != n else out
@@ -386,9 +393,7 @@ def rbgp4_sddmm(
             scratch_shapes=[pltpu.VMEM((dims.tile_m, dcols), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, dims.d_o * dcols), out_dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
+        compiler_params=_params("parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
     )(adj_o, d_out, x)
     return out
@@ -412,17 +417,17 @@ def _rhs_accumulate(dims: KernelDims, x, w, acc_ref, scales=None) -> None:
 
     ``scales`` (u_i, d_i), present iff ``w`` holds int8 leaf blocks:
     each (G, C) leaf block is dequantized in-register (f32 upcast * its
-    per-leaf-block scale) before feeding the MXU, so the f32 accumulator
-    sees the same operand the full-precision kernel would.
+    per-leaf-block scale, then the activation dtype) before feeding the
+    MXU — the operand the full-precision kernel would see for the
+    dequantized weights.
     """
     G, C = dims.group_rows, dims.chunk_cols
+    if scales is not None:
+        w = w.astype(jnp.float32)  # int8 rows pack 32 per vreg; slice in f32
     for ui in range(dims.u_i):
         w_u = w[ui * G:(ui + 1) * G, :]  # (G, d_i*C)
         if scales is not None:
-            w_u = (
-                w_u.astype(jnp.float32).reshape(G, dims.d_i, C)
-                * scales[ui, :][None, :, None]
-            ).reshape(G, dims.d_i * C)
+            w_u = _dequant_row(w_u, scales, ui, C).astype(x.dtype)
         cols = dims.adj_i[ui]
         if len(cols) == dims.v_i:
             x_u = x
@@ -435,6 +440,29 @@ def _rhs_accumulate(dims: KernelDims, x, w, acc_ref, scales=None) -> None:
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+
+def _dequant_row(w_u, s_ref, row: int, C: int):
+    """(G, d*C) f32 values times the scalar scale of each (G, C) leaf block.
+
+    ``s_ref`` holds the tile's scales in SMEM, so each one is a scalar
+    operand; a lane-splitting reshape or a (1, 1) -> (G, C) vector
+    broadcast does not lower on TPU."""
+    n = w_u.shape[1] // C
+    return jnp.concatenate(
+        [w_u[:, t * C:(t + 1) * C] * s_ref[row, t] for t in range(n)], axis=1)
+
+
+def _scales_by_slot(scales: jax.Array, n_slots: int) -> jax.Array:
+    """(..., rows, n_slots*d) leaf-block scales -> (..., n_slots, rows, d).
+
+    A kernel tile reads the (rows_per_tile, d) scales of one outer slot;
+    with the slot leading, that block's last dim is the whole array's, as
+    the TPU lowering requires of a dim narrower than 128 lanes."""
+    *lead, rows, cols = scales.shape
+    s = scales.astype(jnp.float32).reshape(*lead, rows, n_slots,
+                                           cols // n_slots)
+    return jnp.moveaxis(s, -2, -3)
 
 
 def _rhs_writeback(act: Optional[str], acc, b):
@@ -483,8 +511,7 @@ def _mm_rhs_kernel(dims: KernelDims, act: Optional[str], has_bias: bool,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    _rhs_accumulate(dims, x_ref[...], w_ref[...], acc_ref,
-                    scales=s_ref[...] if has_scales else None)
+    _rhs_accumulate(dims, x_ref[...], w_ref[...], acc_ref, scales=s_ref)
 
     @pl.when(kk == dims.d_o - 1)
     def _write():
@@ -522,7 +549,8 @@ def rbgp4mm_rhs(
     holds int8 leaf-block values and each (G, C) leaf block is dequantized
     in-register against its scale before the f32-accumulator contraction
     (the epilogue is unchanged).  Scale columns follow the value tiles'
-    outer-slot order, so the scale operand shares the W block-index map.
+    outer-slot order; the kernel reads them re-laid by outer slot
+    (``_scales_by_slot``) from SMEM.
     """
     m, k = dims.m, dims.k
     if w_data.shape != (m, dims.data_cols):
@@ -589,8 +617,13 @@ def rbgp4mm_rhs(
     if scales is not None:
         # one f32 scale per (G, C) leaf block; the (j, kk) tile owns the
         # (u_i, d_i) scale sub-block matching its value tile
-        in_specs.append(pl.BlockSpec((dims.u_i, dims.d_i), w_map))
-        operands.append(scales.astype(jnp.float32))
+        def s_map(a, b, kk, adj):
+            i, j = ij(a, b)
+            return (kk, j, 0)
+
+        in_specs.append(pl.BlockSpec((None, dims.u_i, dims.d_i), s_map,
+                                     memory_space=pltpu.SMEM))
+        operands.append(_scales_by_slot(scales, dims.d_o))
     if bias is not None:
         in_specs.append(pl.BlockSpec((1, dims.tile_m), b_map))
         operands.append(bias.reshape(1, m))
@@ -619,9 +652,7 @@ def rbgp4mm_rhs(
             scratch_shapes=[pltpu.VMEM((bn, dims.tile_m), jnp.float32)],
         ),
         out_shape=out_shapes,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(adj_o, *operands)
     if save_preact:
@@ -722,9 +753,7 @@ def rbgp4_sddmm_rhs(
             scratch_shapes=[pltpu.VMEM((dims.tile_m, dcols), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, dims.d_o * dcols), out_dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
+        compiler_params=_params("parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
     )(adj_o, g, x)
     return out
@@ -758,8 +787,7 @@ def _mm_rhs_stacked_kernel(dims: KernelDims, act: Optional[str],
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    _rhs_accumulate(dims, x_ref[0], w_ref[0], acc_ref,
-                    scales=s_ref[0] if has_scales else None)
+    _rhs_accumulate(dims, x_ref[0], w_ref[0], acc_ref, scales=s_ref)
 
     @pl.when(kk == dims.d_o - 1)
     def _write():
@@ -835,15 +863,18 @@ def rbgp4mm_rhs_stacked(
     operands = [x, w_data.reshape(e, m, dims.d_o * dcols)]
     if scales is not None:
         in_specs.append(
-            pl.BlockSpec((1, dims.u_i, dims.d_i),
-                         lambda ee, i, j, kk, adj: (ee, j, kk))
+            pl.BlockSpec((None, None, dims.u_i, dims.d_i),
+                         lambda ee, i, j, kk, adj: (ee, kk, j, 0),
+                         memory_space=pltpu.SMEM)
         )
-        operands.append(scales.astype(jnp.float32))
+        operands.append(_scales_by_slot(scales, dims.d_o))
     if bias is not None:
+        # (E, 1, M): the block's last two dims (1, TM) are legal TPU tiles
         in_specs.append(
-            pl.BlockSpec((1, dims.tile_m), lambda ee, i, j, kk, adj: (ee, j))
+            pl.BlockSpec((None, 1, dims.tile_m),
+                         lambda ee, i, j, kk, adj: (ee, 0, j))
         )
-        operands.append(bias)
+        operands.append(bias.reshape(e, 1, m))
 
     out_spec = pl.BlockSpec(
         (1, bn, dims.tile_m), lambda ee, i, j, kk, adj: (ee, i, j)
@@ -868,10 +899,8 @@ def rbgp4mm_rhs_stacked(
             scratch_shapes=[pltpu.VMEM((bn, dims.tile_m), jnp.float32)],
         ),
         out_shape=out_shapes,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
+        compiler_params=_params("parallel", "parallel", "parallel",
+                                "arbitrary"),
         interpret=interpret,
     )(adj_o, *operands)
     if save_preact:
@@ -948,10 +977,8 @@ def rbgp4_sddmm_rhs_stacked(
             scratch_shapes=[pltpu.VMEM((dims.tile_m, dcols), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((e, m, dims.d_o * dcols), out_dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary"),
-        ),
+        compiler_params=_params("parallel", "parallel", "arbitrary",
+                                "arbitrary"),
         interpret=interpret,
     )(adj_o, g, x)
     return out

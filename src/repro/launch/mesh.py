@@ -8,16 +8,12 @@ import so these meshes can be built on the CPU container.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5 exposes explicit axis types; Auto is the old behavior
-    from jax.sharding import AxisType
 
-    def _mesh(shape, axes):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-except ImportError:  # older jax: every mesh axis is implicitly Auto
-    def _mesh(shape, axes):
-        return jax.make_mesh(shape, axes)
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
 
 __all__ = ["make_production_mesh", "make_local_mesh", "make_serve_mesh"]
 
@@ -62,10 +58,5 @@ def make_serve_mesh(dp: int = 1, tp: int = 1, ep: int = 1, *, devices=None):
             f"mesh dp x tp x ep = {need} devices, have {len(devices)}"
         )
     arr = np.asarray(devices[:need], dtype=object).reshape(dp, tp * ep)
-    try:
-        from jax.sharding import AxisType
-
-        return Mesh(arr, ("data", "model"),
-                    axis_types=(AxisType.Auto, AxisType.Auto))
-    except (ImportError, TypeError):
-        return Mesh(arr, ("data", "model"))
+    return Mesh(arr, ("data", "model"),
+                axis_types=(AxisType.Auto, AxisType.Auto))

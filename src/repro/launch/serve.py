@@ -37,6 +37,7 @@ import jax
 import numpy as np
 
 from repro.configs import apply_sparsity, get_config, reduce_config
+from repro.launch.compile_cache import configure_compile_cache
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -153,6 +154,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main():
     args = build_parser().parse_args()
+    configure_compile_cache()
 
     if args.autotune_cache:
         from repro.kernels import autotune

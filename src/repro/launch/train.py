@@ -38,6 +38,7 @@ from repro.configs import (
     reduce_config,
 )
 from repro.data import Prefetcher, TokenStream, host_shard
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import LMModel
 from repro.train import Trainer
 
@@ -144,6 +145,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main():
     args = build_parser().parse_args()
+    configure_compile_cache()
 
     if args.autotune_cache:
         from repro.kernels import autotune

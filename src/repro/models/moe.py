@@ -52,19 +52,6 @@ from .mlp import ACTS, GatedMLP
 __all__ = ["StackedExperts", "MoELayer"]
 
 
-def _shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map moved out of experimental; support both spellings."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=set(mesh.axis_names), check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
-
-
 class StackedExperts:
     """(E, ...) stacked gated-MLP expert weights, RBGP4-maskable.
 
@@ -408,11 +395,11 @@ class MoELayer:
 
         wspec_in = P("model", None, dp)   # (E, h, d): E on model, d FSDP
         wspec_out = P("model", dp, None)  # (E, d, h)
-        y, aux = _shard_map(
-            body, mesh,
+        y, aux = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(P(), wspec_in, wspec_in, wspec_out, P(), P(),
                       P(dp)),
-            out_specs=(P(dp), P(dp)),
+            out_specs=(P(dp), P(dp)), check_vma=False,
         )(router, wg, wu, wd, m_in, m_out,
           x.reshape(T, D).astype(f32))
         return y.reshape(B, S, D).astype(x.dtype), jnp.mean(aux)

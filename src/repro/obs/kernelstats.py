@@ -12,7 +12,8 @@ Two feeds populate one record table keyed by
     roofline estimate;
   * **direct measurement** — :func:`measure_op` times an op's jitted
     ``linear`` with ``block_until_ready`` fencing (warm-up excluded,
-    median of reps) and prices the same shape through
+    median of reps) on the chip the peaks describe (it raises on any
+    other device) and prices the same shape through
     ``kernels.perf_model``, yielding roofline efficiency
     ``model_us / measured_us`` (1.0 = running at the model's
     compute/bandwidth bound; > 1 means the model is conservative).
@@ -163,10 +164,22 @@ def measure_op(op, n: int = 512, *, dtype=None, reps: int = 3,
     (``block_until_ready``) timings.  Records a ``source="direct"`` entry
     and returns the comparison row.  Works regardless of :func:`enable`
     state — calling it is the opt-in.
+
+    Raises ``RuntimeError`` unless the device is the chip the model's peaks
+    describe (``perf_model.PEAK_DEVICE_KIND``): a wall clock taken anywhere
+    else (the CPU, the Pallas interpreter) is not a kernel measurement.
     """
     import jax
     import jax.numpy as jnp
 
+    from repro.kernels.perf_model import PEAK_DEVICE_KIND
+
+    kind = jax.devices()[0].device_kind
+    if kind != PEAK_DEVICE_KIND:
+        raise RuntimeError(
+            f"measure_op times kernels on a {PEAK_DEVICE_KIND!r} device only "
+            f"(the roofline peaks describe that chip); this process runs on "
+            f"{kind!r}")
     if dtype is None:
         dtype = jnp.float32
     dtype_name = jnp.dtype(dtype).name
@@ -191,7 +204,7 @@ def measure_op(op, n: int = 512, *, dtype=None, reps: int = 3,
                        default=dtype_name))
     model_us = _model_us(dims, n, dtype_name, value_dtype, block_n, "rhs")
     key = ("direct_linear", _dims_sig(dims), n, dtype_name, value_dtype,
-           jax.default_backend())
+           kind)
     with _lock:
         rec = _records.get(key)
         if rec is None:

@@ -64,11 +64,16 @@ _PARAM_RULES: list[tuple[str, tuple]] = [
     (r"experts/down", ("M", "F", None)),
     # MLA per-head up-projections (H, r, dn)
     (r"wk_b|wv_b", ("M", None, None)),
-    # row-parallel (input on model): output projections back to d_model
-    (r"(wo|down|cmv|out)/(w|w_data)", ("F", "M")),
+    # row-parallel (input on model): dense/masked output projections back
+    # to d_model.  Compact values (w_data, q_data) stay column-parallel: a
+    # compact column is an adjacency slot, not a slice of the input
+    # features, so a column split would need the whole input on every
+    # device *and* a psum, where a row split needs the input alone
+    # (kernels/tp.py runs the kernels on each device's rows)
+    (r"(wo|down|cmv|out)/w$", ("F", "M")),
     # column-parallel (output on model): everything else projecting out of
     # d_model (wq/wk/wv, gate/up, rwkv r/k/v/g, mamba in/x, mla wq*/wkv_a, ...)
-    (r"/(w|w_data|b)$", ("M", "F")),
+    (r"/(w|w_data|q_data|b)$", ("M", "F")),
 ]
 
 

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.tp import use_kernel_mesh
 from repro.parallel.constrain import activation_mesh
 from repro.parallel.sharding import param_sharding_tree
 
@@ -57,7 +58,8 @@ __all__ = ["ShardedContinuousEngine", "DisaggregatedEngine"]
 def _role_fns(model, mesh, constrain: bool):
     """Jitted (prefill, chunk, decode) programs for one mesh role.
 
-    With ``constrain``, ``activation_mesh`` wraps the model call *inside*
+    Every program is traced inside ``use_kernel_mesh(mesh)``.  With
+    ``constrain``, ``activation_mesh`` also wraps the model call *inside*
     the traced function so ``current_mesh()`` checks in the layers resolve
     at trace time (a context entered outside ``jax.jit`` is gone by the
     time the cached program re-runs); the jit cache then bakes the
@@ -65,8 +67,16 @@ def _role_fns(model, mesh, constrain: bool):
     """
     import contextlib
 
-    ctx = (lambda: activation_mesh(mesh)) if constrain \
-        else contextlib.nullcontext
+    @contextlib.contextmanager
+    def ctx():
+        # Pallas-backed layers find the mesh at trace time and run their
+        # kernels under shard_map (kernels/tp.py)
+        with use_kernel_mesh(mesh):
+            if constrain:
+                with activation_mesh(mesh):
+                    yield
+            else:
+                yield
 
     def prefill(params, batch, cache):
         with ctx():

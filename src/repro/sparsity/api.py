@@ -84,6 +84,7 @@ import jax.numpy as jnp
 from repro.core import RBGP4Layout
 from repro.kernels import EPILOGUE_ACTS, get_op
 from repro.kernels import ref as kref
+from repro.kernels.tp import kernel_mesh, linear_column_parallel
 
 __all__ = [
     "BackendCapabilities",
@@ -666,7 +667,9 @@ class PallasBackend:
     never rebuild static kernel metadata.  Declares ``epilogue``
     (bias/act/residual fused into the kernel write-back) and ``batched``
     (one stacked-grid launch for E experts); ``block_n="auto"`` resolves
-    through the autotuner cache per (dims, dtype, platform).
+    through the autotuner cache per (dims, dtype, platform).  A program
+    traced under a multi-device mesh runs the column-parallel kernels of
+    :mod:`repro.kernels.tp` (forward only) in place of ``RBGP4Op.linear``.
     """
 
     name = "pallas"
@@ -677,14 +680,23 @@ class PallasBackend:
     accepts = (CompactWeight,)
 
     def linear(self, weight, x):
-        return get_op(weight.layout).linear(x, weight.w_data.astype(x.dtype))
+        return self._linear(weight, x)
 
     def linear_fused(self, weight, x, *, fuse=None, residual=None):
         b = weight.b.astype(x.dtype) if weight.b is not None else None
-        return get_op(weight.layout).linear(
-            x, weight.w_data.astype(x.dtype),
-            bias=b, fuse=fuse, residual=residual,
-        )
+        return self._linear(weight, x, bias=b, fuse=fuse, residual=residual)
+
+    @staticmethod
+    def _linear(weight, x, *, bias=None, fuse=None, residual=None):
+        op = get_op(weight.layout)
+        w_data = weight.w_data.astype(x.dtype)
+        mesh = kernel_mesh()
+        if mesh is None:
+            return op.linear(x, w_data, bias=bias, fuse=fuse,
+                             residual=residual)
+        return linear_column_parallel(
+            op.dims, op.adj_o, x, w_data, mesh=mesh, bias=bias, act=fuse,
+            residual=residual, interpret=op.interpret, out_dtype=x.dtype)
 
     def linear_batched(self, weight, x, *, fuse=None):
         b = weight.b.astype(x.dtype) if weight.b is not None else None
@@ -779,9 +791,14 @@ class QuantBackend:
             else:
                 from repro.kernels import rbgp4mm
 
+                dims = rbgp4mm.kernel_dims(lay)
+                mesh = kernel_mesh()
+                if mesh is not None:
+                    return linear_column_parallel(
+                        dims, lay.adj_o, x, weight.q_data, mesh=mesh,
+                        scales=weight.scales, out_dtype=x.dtype)
                 y = rbgp4mm.rbgp4mm_rhs(
-                    rbgp4mm.kernel_dims(lay),
-                    jnp.asarray(lay.adj_o, jnp.int32),
+                    dims, jnp.asarray(lay.adj_o, jnp.int32),
                     x2, weight.q_data, scales=weight.scales,
                     out_dtype=x.dtype,
                 )

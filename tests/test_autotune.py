@@ -1,4 +1,5 @@
 """Autotuner: search, persistent cache round-trip, block_n="auto" wiring."""
+import importlib
 import json
 import os
 
@@ -9,6 +10,8 @@ import pytest
 
 from repro.core import RBGP4Layout, RBGP4Spec
 from repro.kernels import KernelDims, autotune, rbgp4mm_rhs, ref
+
+K = importlib.import_module("repro.kernels.rbgp4mm")
 
 
 @pytest.fixture(autouse=True)
@@ -133,18 +136,26 @@ def test_unwritable_cache_degrades_gracefully():
 def test_vmem_bound_prunes_huge_tiles():
     # tall tiles: tile_m = 64*16 = 1024 rows -> 2048-wide token tiles would
     # blow the acc budget
-    lay = make_dims(m=4096, k=4096, G=16, C=128, ui=4, vi=4, sp_o=0.75,
+    lay = make_dims(m=4096, k=4096, G=16, C=128, ui=64, vi=4, sp_o=0.75,
                     sp_i=0.0, seed=5)
     dims = KernelDims.from_layout(lay)
     cands = autotune.candidate_block_ns(dims, 1 << 16, "bfloat16")
-    assert cands
-    for bn in cands:
-        working = (bn * dims.tile_m * 4
-                   + 2 * bn * dims.tile_k * 2
-                   + 2 * dims.tile_m * dims.d_o * dims.d_i
-                   * dims.chunk_cols * 2
-                   + 2 * bn * dims.tile_m * 2)
-        assert working <= autotune.VMEM_BUDGET_BYTES
+    assert cands and cands[-1] < autotune.BLOCK_N_CANDIDATES[-1]
+    for bn in autotune.BLOCK_N_CANDIDATES:
+        fits = (autotune.working_set_bytes(dims, bn, "rhs", 2, 2)
+                <= autotune.VMEM_BUDGET_BYTES)
+        assert fits == (bn in cands), bn
+    # the budget leaves the compiler's own scratch half the scoped limit
+    assert 2 * autotune.VMEM_BUDGET_BYTES == K.VMEM_LIMIT_BYTES
+    # the fused "rhs" variant counts more than a plain one; the SDDMM's
+    # accumulator is the (TM, d_i*C) dW tile, not the (BN, TM) output
+    ws = {kind: autotune.working_set_bytes(dims, 1024, kind, 2, 2)
+          for kind in ("rhs", "lhs", "sddmm")}
+    assert ws["rhs"] > ws["lhs"] > 0 and ws["sddmm"] > 0
+    # int8 values: fewer W bytes, plus their f32 upcast
+    w_tile = dims.tile_m * dims.d_i * dims.chunk_cols
+    assert (autotune.working_set_bytes(dims, 1024, "rhs", 2, 1)
+            == ws["rhs"] - 2 * w_tile + 4 * w_tile)
 
 
 # ---------------------------------------------------------------------------
@@ -248,6 +259,46 @@ def test_measured_mode_chain_rhs(monkeypatch, fake_timer):
     assert res.block_n == seen[0]
     disk = json.load(open(autotune.cache_path()))["entries"]
     assert any(k.startswith("chain_rhs|tpu|") for k in disk)
+
+
+@pytest.mark.parametrize("message, pruned", [
+    ("RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem", True),
+    ("INTERNAL: Mosaic failed to compile TPU kernel", False),
+])
+def test_measured_mode_prunes_only_memory_refusals(monkeypatch, fake_timer,
+                                                   message, pruned):
+    """A candidate the compiler refuses for memory is skipped; any other
+    compile or run failure propagates, and a search in which no candidate
+    compiles raises instead of falling back to the model's choice."""
+    lay = make_dims(seed=9)
+    dims = KernelDims.from_layout(lay)
+    big = autotune.candidate_block_ns(dims, 512, "float32")[-1]
+
+    def stub_rhs(d, adj, x, w, block_n=None, grid_order="nm", **kw):
+        if block_n == big:
+            raise jax.errors.JaxRuntimeError(message)
+        return jnp.zeros((x.shape[0], d.m), x.dtype)
+
+    monkeypatch.setattr(K, "rbgp4mm_rhs", stub_rhs)
+    monkeypatch.setenv("REPRO_AUTOTUNE_MODE", "measure")
+    run = lambda: autotune.autotune(dims, 512, dtype="float32", kind="rhs",
+                                    platform="tpu",
+                                    adj_o=np.asarray(lay.adj_o))
+    if not pruned:
+        with pytest.raises(jax.errors.JaxRuntimeError, match="Mosaic"):
+            run()
+        return
+    res = run()
+    assert res.source == "measured" and res.block_n != big
+    # nothing compiles: raise, never a model-mode fallback
+    def refuse(*a, **k):
+        raise jax.errors.JaxRuntimeError(message)
+
+    monkeypatch.setattr(K, "rbgp4mm_rhs", refuse)
+    autotune.clear_memory_cache()
+    autotune.set_cache_path(autotune.cache_path() + ".2")
+    with pytest.raises(RuntimeError, match="compiles"):
+        run()
 
 
 def test_measured_mode_requires_adjacency(monkeypatch):

@@ -304,9 +304,16 @@ def test_gated_mlp_forward_exercises_fused_epilogue():
 
     gp = jax.grad(loss(mlp_pallas))(params)
     gr = jax.grad(loss(mlp_ref))(params)
-    np.testing.assert_allclose(np.asarray(gp["gate"].w_data),
-                               np.asarray(gr["gate"].w_data),
-                               rtol=1e-4, atol=1e-5)
+    g_ref = np.asarray(gr["gate"].w_data)
+    # The SDDMM kernel and the dense reference sum the 16 token products
+    # (and the 128-term upstream dh contraction) in different orders.  f32
+    # reassociation error is bounded by ~n * eps * sum|terms|, i.e. a few
+    # ulps of the array's scale, not of each entry: an entry that cancels
+    # to near zero can miss a fixed 1e-5 while being exact to 3e-7 of the
+    # gradient's magnitude.  So the absolute tolerance is 16 eps of it.
+    atol = 16 * np.finfo(np.float32).eps * float(np.abs(g_ref).max())
+    np.testing.assert_allclose(np.asarray(gp["gate"].w_data), g_ref,
+                               rtol=1e-4, atol=atol)
 
 
 def test_get_op_is_cached_per_layout():

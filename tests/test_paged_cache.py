@@ -14,7 +14,9 @@ re-deriving them —
     allocation never exhausts the pool (the worst-case reservation
     argument), and finishing a request returns all of its blocks.
 """
+import os
 import types
+from unittest import mock
 
 import pytest
 
@@ -120,40 +122,42 @@ def test_free_rejects_duplicates_in_one_call():
         max_size=80,
     ),
 )
-def test_allocator_invariants_under_quarantine(n_blocks, ops, monkeypatch):
+def test_allocator_invariants_under_quarantine(n_blocks, ops):
     """check_invariants() (armed via REPRO_SERVE_CHECKS=1, as the serve
     debug mode does) holds after arbitrary interleavings of alloc/free
     with fault-injected quarantine/restore, and the three sets stay a
     disjoint partition with conservation."""
-    monkeypatch.setenv("REPRO_SERVE_CHECKS", "1")
-    a = PageAllocator(n_blocks)
-    held: list[list[int]] = []
-    for kind, n in ops:
-        if kind == "alloc":
-            if a.can_alloc(n):
-                held.append(a.alloc(n))
+    # set inside the body: hypothesis refuses function-scoped fixtures
+    # (monkeypatch) under @given
+    with mock.patch.dict(os.environ, {"REPRO_SERVE_CHECKS": "1"}):
+        a = PageAllocator(n_blocks)
+        held: list[list[int]] = []
+        for kind, n in ops:
+            if kind == "alloc":
+                if a.can_alloc(n):
+                    held.append(a.alloc(n))
+                else:
+                    with pytest.raises(RuntimeError):
+                        a.alloc(n)
+            elif kind == "free":
+                if held:
+                    a.free(held.pop(n % len(held)))
+            elif kind == "quarantine":
+                taken = a.quarantine(n)
+                assert taken <= n
             else:
-                with pytest.raises(RuntimeError):
-                    a.alloc(n)
-        elif kind == "free":
-            if held:
-                a.free(held.pop(n % len(held)))
-        elif kind == "quarantine":
-            taken = a.quarantine(n)
-            assert taken <= n
-        else:
-            back = a.restore_quarantined(n if n else None)
-            assert back <= (n or n_blocks)
+                back = a.restore_quarantined(n if n else None)
+                assert back <= (n or n_blocks)
+            a.check_invariants()
+            # capacity shrinks exactly by what is quarantined
+            assert a.n_total == n_blocks - 1 - a.n_quarantined
+            assert a.n_free + a.n_allocated == a.n_total
+            assert a.n_allocated == sum(len(b) for b in held)
+        a.restore_quarantined()
+        for blocks in held:
+            a.free(blocks)
         a.check_invariants()
-        # capacity shrinks exactly by what is quarantined
-        assert a.n_total == n_blocks - 1 - a.n_quarantined
-        assert a.n_free + a.n_allocated == a.n_total
-        assert a.n_allocated == sum(len(b) for b in held)
-    a.restore_quarantined()
-    for blocks in held:
-        a.free(blocks)
-    a.check_invariants()
-    assert a.n_free == a.n_total == n_blocks - 1
+        assert a.n_free == a.n_total == n_blocks - 1
 
 
 # -- scheduler ---------------------------------------------------------------------
@@ -312,66 +316,68 @@ def test_share_rejects_unallocated_and_release_rejects_duplicates():
         max_size=100,
     ),
 )
-def test_allocator_invariants_under_sharing(n_blocks, ops, monkeypatch):
+def test_allocator_invariants_under_sharing(n_blocks, ops):
     """share/release interleaved with alloc/free/quarantine/restore:
     conservation holds, a block is never freed while referenced, and the
     armed check_invariants() (the refcount partition included) passes
     after every operation — the bookkeeping contract the prefix cache
     (engine + radix index) is built on."""
-    monkeypatch.setenv("REPRO_SERVE_CHECKS", "1")
-    a = PageAllocator(n_blocks)
-    refs: dict[int, int] = {}    # mirror of expected refcounts
-    for kind, n in ops:
-        live = sorted(refs)
-        if kind == "alloc":
-            if a.can_alloc(n):
-                for b in a.alloc(n):
-                    assert b not in refs, "double-allocated block"
-                    refs[b] = 1
-            else:
-                with pytest.raises(RuntimeError):
-                    a.alloc(n)
-        elif kind == "share" and live:
-            b = live[n % len(live)]
-            a.share([b])
-            refs[b] += 1
-        elif kind == "release" and live:
-            b = live[n % len(live)]
-            freed = a.release([b])
-            refs[b] -= 1
-            if refs[b] == 0:
-                assert freed == [b]
-                del refs[b]
-            else:
-                assert freed == []
-        elif kind == "free" and live:
-            b = live[n % len(live)]
-            if refs[b] == 1:
-                a.free([b])
-                del refs[b]
-            else:
-                # free-while-referenced must be refused (and change nothing)
-                with pytest.raises(ValueError):
+    # set inside the body: hypothesis refuses function-scoped fixtures
+    # (monkeypatch) under @given
+    with mock.patch.dict(os.environ, {"REPRO_SERVE_CHECKS": "1"}):
+        a = PageAllocator(n_blocks)
+        refs: dict[int, int] = {}    # mirror of expected refcounts
+        for kind, n in ops:
+            live = sorted(refs)
+            if kind == "alloc":
+                if a.can_alloc(n):
+                    for b in a.alloc(n):
+                        assert b not in refs, "double-allocated block"
+                        refs[b] = 1
+                else:
+                    with pytest.raises(RuntimeError):
+                        a.alloc(n)
+            elif kind == "share" and live:
+                b = live[n % len(live)]
+                a.share([b])
+                refs[b] += 1
+            elif kind == "release" and live:
+                b = live[n % len(live)]
+                freed = a.release([b])
+                refs[b] -= 1
+                if refs[b] == 0:
+                    assert freed == [b]
+                    del refs[b]
+                else:
+                    assert freed == []
+            elif kind == "free" and live:
+                b = live[n % len(live)]
+                if refs[b] == 1:
                     a.free([b])
-                assert a.refcount(b) == refs[b]
-        elif kind == "quarantine":
-            taken = a.quarantine(n)
-            assert taken <= n
-        elif kind == "restore":
-            a.restore_quarantined(n if n else None)
+                    del refs[b]
+                else:
+                    # free-while-referenced must be refused (and change nothing)
+                    with pytest.raises(ValueError):
+                        a.free([b])
+                    assert a.refcount(b) == refs[b]
+            elif kind == "quarantine":
+                taken = a.quarantine(n)
+                assert taken <= n
+            elif kind == "restore":
+                a.restore_quarantined(n if n else None)
+            a.check_invariants()
+            assert a.n_allocated == len(refs)
+            assert a.n_free + a.n_allocated == a.n_total
+            for b, r in refs.items():
+                assert a.refcount(b) == r
+        a.restore_quarantined()
+        for b in sorted(refs):
+            while refs[b] > 1:
+                a.release([b])
+                refs[b] -= 1
+            a.free([b])
         a.check_invariants()
-        assert a.n_allocated == len(refs)
-        assert a.n_free + a.n_allocated == a.n_total
-        for b, r in refs.items():
-            assert a.refcount(b) == r
-    a.restore_quarantined()
-    for b in sorted(refs):
-        while refs[b] > 1:
-            a.release([b])
-            refs[b] -= 1
-        a.free([b])
-    a.check_invariants()
-    assert a.n_free == a.n_total == n_blocks - 1
+        assert a.n_free == a.n_total == n_blocks - 1
 
 
 def test_restore_quarantined_is_sorted_deterministic():

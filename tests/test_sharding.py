@@ -17,12 +17,9 @@ from jax.sharding import PartitionSpec as P
 from repro.parallel.sharding import param_spec, dp_axes, cache_specs
 from repro.parallel.constrain import activation_mesh, shard
 
-try:
-    from jax.sharding import AxisType
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                         axis_types=(AxisType.Auto,) * 3)
-except ImportError:  # older jax: mesh axes are implicitly Auto
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(AxisType.Auto,) * 3)
 
 # -- param rules --------------------------------------------------------------
 assert dp_axes(mesh) == ("pod", "data")
@@ -31,7 +28,11 @@ cases = {
     ("params/embed/0/embedding", (64, 32)): P("model", ("pod", "data")),
     ("params/stack/head/0/mixer/wq/w", (32, 16)): P("model", ("pod", "data")),
     ("params/stack/head/0/mixer/wo/w", (16, 32)): P(("pod", "data"), "model"),
-    ("params/stack/head/0/ffn/down/w_data", (16, 8)): P(("pod", "data"), "model"),
+    # compact values are column-parallel even on row-parallel projections
+    ("params/stack/head/0/ffn/down/w_data", (16, 8)):
+        P("model", ("pod", "data")),
+    ("params/stack/head/0/ffn/down/q_data", (16, 8)):
+        P("model", ("pod", "data")),
     ("params/stack/head/0/norm1/scale", (16,)): P(None),
     ("params/stack/head/0/ffn/_ba_o", (4, 4)): P(None, None),
     ("params/stack/head/0/ffn/moe/router", (8, 16)): P(None, None),
