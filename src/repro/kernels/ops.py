@@ -140,35 +140,44 @@ class RBGP4Op:
     # -- transpose of the compact storage (static gather) -------------------
     def transpose_data(self, w_data: jax.Array) -> jax.Array:
         """WdataT such that it packs W^T under the transposed layout."""
-        perm = jnp.asarray(self._t_perm)
-        return jnp.take(w_data.reshape(-1), perm).reshape(self.dims_t.m, -1)
+        with jax.named_scope("rbgp4.transpose_data"):
+            perm = jnp.asarray(self._t_perm)
+            return jnp.take(w_data.reshape(-1), perm).reshape(
+                self.dims_t.m, -1)
 
     def transpose_data_stacked(self, w_data: jax.Array) -> jax.Array:
         """Per-expert transpose of stacked (E, M, nnz_row) compact values."""
         e = w_data.shape[0]
-        perm = jnp.asarray(self._t_perm)
-        return jnp.take(
-            w_data.reshape(e, -1), perm, axis=1
-        ).reshape(e, self.dims_t.m, -1)
+        with jax.named_scope("rbgp4.transpose_data"):
+            perm = jnp.asarray(self._t_perm)
+            return jnp.take(
+                w_data.reshape(e, -1), perm, axis=1
+            ).reshape(e, self.dims_t.m, -1)
 
     # -- forward/backward ----------------------------------------------------
+    # named scopes (``rbgp4.fwd``, ``rbgp4.sddmm``, ``rbgp4.dx``,
+    # ``rbgp4.transpose_data``) mark each kernel call in the ops' HLO
+    # ``op_name`` metadata, so a profiler trace finds them by name
     def _fwd_mm(self, w_data, x):
-        return rbgp4mm(
-            self.dims, jnp.asarray(self.adj_o), w_data, x,
-            block_n=self.block_n, interpret=self.interpret,
-        )
+        with jax.named_scope("rbgp4.fwd"):
+            return rbgp4mm(
+                self.dims, jnp.asarray(self.adj_o), w_data, x,
+                block_n=self.block_n, interpret=self.interpret,
+            )
 
     def _fwd_mm_t(self, w_data_t, g):
-        return rbgp4mm(
-            self.dims_t, jnp.asarray(self.adj_o_t), w_data_t, g,
-            block_n=self.block_n, interpret=self.interpret,
-        )
+        with jax.named_scope("rbgp4.dx"):
+            return rbgp4mm(
+                self.dims_t, jnp.asarray(self.adj_o_t), w_data_t, g,
+                block_n=self.block_n, interpret=self.interpret,
+            )
 
     def _sddmm(self, g, x):
-        return rbgp4_sddmm(
-            self.dims, jnp.asarray(self.adj_o), g, x,
-            block_n=self.block_n, interpret=self.interpret,
-        )
+        with jax.named_scope("rbgp4.sddmm"):
+            return rbgp4_sddmm(
+                self.dims, jnp.asarray(self.adj_o), g, x,
+                block_n=self.block_n, interpret=self.interpret,
+            )
 
     def _act_bwd(self, fuse: str, z: jax.Array, g: jax.Array) -> jax.Array:
         """dz = g * act'(z), elementwise (fused by XLA into the surrounds)."""
@@ -182,11 +191,12 @@ class RBGP4Op:
         adj_t = lambda: jnp.asarray(self.adj_o_t)
 
         def run(w_data, x2, b, r, save_preact):
-            return rbgp4mm_rhs(
-                self.dims, adj(), x2, w_data,
-                block_n=self.block_n, interpret=self.interpret,
-                bias=b, act=fuse, residual=r, save_preact=save_preact,
-            )
+            with jax.named_scope("rbgp4.fwd"):
+                return rbgp4mm_rhs(
+                    self.dims, adj(), x2, w_data,
+                    block_n=self.block_n, interpret=self.interpret,
+                    bias=b, act=fuse, residual=r, save_preact=save_preact,
+                )
 
         @jax.custom_vjp
         def linear_rhs(w_data, x2, b, r):
@@ -208,15 +218,18 @@ class RBGP4Op:
             db = gz.sum(0).astype(b.dtype) if has_bias else None
             # token-major SDDMM: consumes (N, M)/(N, K) directly — the old
             # path paid two full transposes (g.T, x2.T) here
-            dw = rbgp4_sddmm_rhs(
-                self.dims, adj(), gz, x2,
-                block_n=self.block_n, interpret=self.interpret,
-            ).astype(w_data.dtype)
+            with jax.named_scope("rbgp4.sddmm"):
+                dw = rbgp4_sddmm_rhs(
+                    self.dims, adj(), gz, x2,
+                    block_n=self.block_n, interpret=self.interpret,
+                ).astype(w_data.dtype)
             # dx = gz @ W_s via the RHS kernel on the transposed layout
-            dx = rbgp4mm_rhs(
-                self.dims_t, adj_t(), gz, self.transpose_data(w_data),
-                block_n=self.block_n, interpret=self.interpret,
-            ).astype(x2.dtype)
+            w_t = self.transpose_data(w_data)
+            with jax.named_scope("rbgp4.dx"):
+                dx = rbgp4mm_rhs(
+                    self.dims_t, adj_t(), gz, w_t,
+                    block_n=self.block_n, interpret=self.interpret,
+                ).astype(x2.dtype)
             return dw, dx, db, dr
 
         linear_rhs.defvjp(fwd, bwd)
@@ -235,11 +248,12 @@ class RBGP4Op:
         adj_t = lambda: jnp.asarray(self.adj_o_t)
 
         def run(w_data, x, b, save_preact):
-            return rbgp4mm_rhs_stacked(
-                self.dims, adj(), x, w_data,
-                block_n=self.block_n, interpret=self.interpret,
-                bias=b, act=fuse, save_preact=save_preact,
-            )
+            with jax.named_scope("rbgp4.fwd"):
+                return rbgp4mm_rhs_stacked(
+                    self.dims, adj(), x, w_data,
+                    block_n=self.block_n, interpret=self.interpret,
+                    bias=b, act=fuse, save_preact=save_preact,
+                )
 
         @jax.custom_vjp
         def linear_stacked(w_data, x, b):
@@ -256,14 +270,17 @@ class RBGP4Op:
             g = g.astype(x.dtype)  # (E, N, M)
             gz = self._act_bwd(fuse, z, g) if fuse is not None else g
             db = gz.sum(1).astype(b.dtype) if has_bias else None
-            dw = rbgp4_sddmm_rhs_stacked(
-                self.dims, adj(), gz, x,
-                block_n=self.block_n, interpret=self.interpret,
-            ).astype(w_data.dtype)
-            dx = rbgp4mm_rhs_stacked(
-                self.dims_t, adj_t(), gz, self.transpose_data_stacked(w_data),
-                block_n=self.block_n, interpret=self.interpret,
-            ).astype(x.dtype)
+            with jax.named_scope("rbgp4.sddmm"):
+                dw = rbgp4_sddmm_rhs_stacked(
+                    self.dims, adj(), gz, x,
+                    block_n=self.block_n, interpret=self.interpret,
+                ).astype(w_data.dtype)
+            w_t = self.transpose_data_stacked(w_data)
+            with jax.named_scope("rbgp4.dx"):
+                dx = rbgp4mm_rhs_stacked(
+                    self.dims_t, adj_t(), gz, w_t,
+                    block_n=self.block_n, interpret=self.interpret,
+                ).astype(x.dtype)
             return dw, dx, db
 
         linear_stacked.defvjp(fwd, bwd)

@@ -316,12 +316,21 @@ def main():
     print(f"served {len(out)} requests ({n_prompt} prompt + {n_gen} new "
           f"tokens) in {wall*1e3:.0f}ms end-to-end "
           f"({(n_prompt + n_gen)/max(wall, 1e-9):.0f} tok/s incl. compile)")
-    print(f"prefill: {n_prompt} tokens, {int(st['prefill_calls'])} calls "
-          f"in {st['prefill_time_s']*1e3:.0f}ms")
-    print(f"decode : {n_gen} tokens, {int(st['decode_steps'])} steps in "
-          f"{st['decode_time_s']*1e3:.0f}ms "
-          f"({n_gen/max(st['decode_time_s'], 1e-9):.0f} tok/s, "
-          f"{int(st['wasted_row_steps'])} wasted row-steps)")
+    print(f"prefill: {n_prompt} tokens, {int(st['prefill_calls'])} calls")
+    print(f"decode : {n_gen} tokens, {int(st['decode_steps'])} steps, "
+          f"{int(st['wasted_row_steps'])} wasted row-steps")
+    # host time of each serve.* span (repro.obs.span): dispatches are not
+    # waited on, so device time shows up in the blocking reads ("fetch")
+    phases = [k[:-len("_calls")] for k in st
+              if k.endswith("_calls") and f"{k[:-len('_calls')]}_s" in st]
+    if phases:
+        print("host ms per phase (calls): " + ", ".join(
+            f"{p} {st[p + '_s'] * 1e3:.0f} ({int(st[p + '_calls'])})"
+            for p in phases))
+    if st["admissions"]:
+        print(f"queue wait: {st['queue_wait_s'] / st['admissions'] * 1e3:.1f}"
+              f"ms mean over {int(st['admissions'])} admissions; "
+              f"{int(st['device_syncs'])} device syncs")
     if args.engine != "static":
         occ = st["allocated_block_steps"] / max(st["block_steps"], 1)
         print(f"paged KV: page={args.page_size} "

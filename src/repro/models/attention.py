@@ -144,17 +144,20 @@ def paged_cache_update(pages, new_vals, positions, block_tables):
     phys = jnp.where(active, bt_cur, 0)
     off = jnp.where(active, slot % P, 0)
     out = {}
-    for name, val in new_vals.items():
-        buf = pages[name]
-        out[name] = buf.at[phys, off].set(val[:, 0].astype(buf.dtype))
-    out["pos"] = pages["pos"].at[phys, off].set(jnp.where(active, slot, -1))
-    safe = jnp.maximum(block_tables, 0)
-    gathered = {
-        name: out[name][safe].reshape((B, MB * P) + out[name].shape[2:])
-        for name in new_vals
-    }
-    valid = jnp.repeat(block_tables >= 0, P, axis=1)
-    k_pos = jnp.where(valid, out["pos"][safe].reshape(B, MB * P), -1)
+    with jax.named_scope("attn.kv_write"):
+        for name, val in new_vals.items():
+            buf = pages[name]
+            out[name] = buf.at[phys, off].set(val[:, 0].astype(buf.dtype))
+        out["pos"] = pages["pos"].at[phys, off].set(
+            jnp.where(active, slot, -1))
+    with jax.named_scope("attn.kv_gather"):
+        safe = jnp.maximum(block_tables, 0)
+        gathered = {
+            name: out[name][safe].reshape((B, MB * P) + out[name].shape[2:])
+            for name in new_vals
+        }
+        valid = jnp.repeat(block_tables >= 0, P, axis=1)
+        k_pos = jnp.where(valid, out["pos"][safe].reshape(B, MB * P), -1)
     return out, gathered, k_pos
 
 

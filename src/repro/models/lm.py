@@ -101,6 +101,7 @@ class LMModel:
             x = jnp.concatenate([patch_embeds.astype(dtype), x[:, npatch:]], axis=1)
         return x
 
+    @jax.named_scope("lm.head")
     def _head(self, params, x):
         cfg = self.cfg
         if cfg.tie_embeddings:

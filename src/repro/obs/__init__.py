@@ -9,8 +9,10 @@ packages at module level.
 
 Entry points:
 
-  * :class:`Recorder` / :data:`NULL_RECORDER` — the engines' recorder
-    duck type (``repro.obs.record``);
+  * :class:`span` — the one timing primitive: a profiler annotation plus
+    ``<phase>_s``/``<phase>_calls`` counters, never a fence;
+    :class:`Recorder` / :data:`NULL_RECORDER` — the engines' recorder
+    duck type, whose ``span()`` is that primitive (``repro.obs.record``);
   * :class:`MetricsRegistry` / :class:`EngineStats` — counters, gauges,
     histograms; snapshot + Prometheus rendering (``repro.obs.metrics``);
   * :class:`SpanLog` — per-request TTFT/TPOT/queue/preemption spans
@@ -28,7 +30,7 @@ from .audit import audit_engine, derive_counts
 from .metrics import (SCHEMA_VERSION, Counter, Gauge, Histogram,
                       MetricsRegistry, EngineStats, bench_payload,
                       exponential_buckets, DURATION_BUCKETS_S)
-from .record import NULL_RECORDER, NullRecorder, Recorder, fence
+from .record import NULL_RECORDER, NullRecorder, Recorder, span
 from .spans import RequestSpan, Segment, SpanLog, percentile, percentile_table
 from .trace import TraceBuffer, validate_trace, validate_trace_file
 
@@ -36,7 +38,7 @@ __all__ = [
     "SCHEMA_VERSION",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "EngineStats",
     "bench_payload", "exponential_buckets", "DURATION_BUCKETS_S",
-    "Recorder", "NullRecorder", "NULL_RECORDER", "fence",
+    "span", "Recorder", "NullRecorder", "NULL_RECORDER",
     "SpanLog", "RequestSpan", "Segment", "percentile", "percentile_table",
     "TraceBuffer", "validate_trace", "validate_trace_file",
     "audit_engine", "derive_counts",

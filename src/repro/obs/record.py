@@ -1,83 +1,86 @@
-"""The Recorder: the one object the engines talk to for observability.
+"""The span primitive and the Recorder, the one object the engines talk to.
 
-Two implementations share one duck type:
+:class:`span` is the program's only timing primitive.  ``with span(name,
+stats):`` does two things:
 
-  * :data:`NULL_RECORDER` (a :class:`NullRecorder`) — the default.  Every
-    hook is a no-op except ``timed()``, which preserves the engines'
-    historical behavior byte-for-byte: a bare ``perf_counter`` delta
-    added into the ``stats`` dict, **without** fencing JAX's async
-    dispatch.  Nothing is allocated per call, no registry, no spans, no
-    trace — zero overhead and zero behavior change when observability is
-    off.
-  * :class:`Recorder` — the real thing.  ``timed()`` additionally
-    *fences* (``block_until_ready`` on every pytree leaf handed to
-    ``tm.fence``) before stopping the clock, observes a
-    ``<name>_seconds`` histogram, and emits a Perfetto slice; lifecycle
-    hooks feed the :class:`~repro.obs.spans.SpanLog`; ``instant()``
-    marks point events on the trace.
+  * it opens a ``jax.profiler.TraceAnnotation(name)``, so when a profiler
+    session is running the span lands in the ``.xplane.pb`` host plane on
+    the same clock as the device's ``XLA Ops``;
+  * on exit it adds the elapsed ``perf_counter`` seconds and one call to
+    two plain counters of ``stats``: ``<phase>_s`` and ``<phase>_calls``,
+    where the phase is the name after its last dot (``serve.decode`` ->
+    ``decode_s``, ``decode_calls``).
 
-The fence is the satellite bugfix for the async-dispatch timing bug:
-``prefill_time_s``/``decode_time_s`` used to stop the clock after JAX
-*dispatch* returned, not after the computation ran (materializing logits
-forces only part of the program, and chunked prefill's non-final chunks
-force nothing at all).  With a recorder attached the timed section calls
-``tm.fence(cache)`` / ``tm.fence(pools)`` so the wall-clock covers the
-compute.  The null recorder deliberately keeps the old (cheap, unfenced)
-numbers — fencing would serialize dispatch and slow serving down when
-nobody is looking at the timings.
+It never fences (``block_until_ready``): a span around a dispatch times
+the host's work, and the device's time comes from the profiler.  So
+attaching a recorder changes neither dispatch nor what a span measures.
+
+Two recorders share one duck type:
+
+  * :data:`NULL_RECORDER` (a :class:`NullRecorder`) — the default.  Its
+    ``span()`` is the bare primitive; every other hook is a no-op.
+  * :class:`Recorder` — its ``span()`` is the same primitive with the
+    recorder as sink: each closed span also observes a
+    ``<name>_seconds`` histogram and becomes a Perfetto slice of the
+    same name; lifecycle hooks feed the :class:`~repro.obs.spans.SpanLog`;
+    ``instant()`` marks point events on the trace.
 """
 from __future__ import annotations
 
 import time
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 from .metrics import MetricsRegistry
 from .spans import SpanLog
 from .trace import TraceBuffer
 
-__all__ = ["Recorder", "NullRecorder", "NULL_RECORDER", "fence"]
+__all__ = ["span", "Recorder", "NullRecorder", "NULL_RECORDER"]
+
+_KEYS: dict[str, tuple[str, str]] = {}
 
 
-def fence(x):
-    """``block_until_ready`` every array leaf of a pytree; returns x.
-
-    Tolerates non-JAX leaves (numpy arrays, test fakes without the
-    method) so callers can fence whatever object they have in hand.
-    """
-    import jax
-
-    for leaf in jax.tree_util.tree_leaves(x):
-        bur = getattr(leaf, "block_until_ready", None)
-        if bur is not None:
-            bur()
-    return x
+def _keys(name: str) -> tuple[str, str]:
+    keys = _KEYS.get(name)
+    if keys is None:
+        phase = name.rpartition(".")[2]
+        keys = _KEYS[name] = (f"{phase}_s", f"{phase}_calls")
+    return keys
 
 
-class _NullTimed:
-    """Context manager reproducing the engines' historical timing code:
-    ``stats[key] += perf_counter() - t0`` around the (un-fenced) calls."""
+class span:
+    """Context manager: a profiler annotation plus two counters in
+    ``stats`` (see the module docstring).  ``stats=None`` keeps the
+    annotation only; ``sink`` (a :class:`Recorder`) gets
+    ``on_span(name, t0, t1)`` at exit.  After exit ``elapsed`` holds the
+    span's seconds."""
 
-    __slots__ = ("_stats", "_key", "_t0")
+    __slots__ = ("name", "_stats", "_sink", "_ann", "t0", "elapsed")
 
-    def __init__(self, stats, key):
+    def __init__(self, name: str, stats=None, sink=None):
+        self.name = name
         self._stats = stats
-        self._key = key
+        self._sink = sink
+        self._ann = TraceAnnotation(name)
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        if self._stats is not None and self._key is not None:
-            self._stats[self._key] += time.perf_counter() - self._t0
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self.elapsed = t1 - self.t0
+        st = self._stats
+        if st is not None:
+            ks, kn = _keys(self.name)
+            st[ks] = st.get(ks, 0.0) + self.elapsed
+            st[kn] = st.get(kn, 0) + 1
+        if self._sink is not None:
+            self._sink.on_span(self.name, self.t0, t1)
         return False
-
-    @staticmethod
-    def fence(x):
-        return x
-
-    def set(self, **kw) -> None:
-        pass
 
 
 class NullRecorder:
@@ -88,18 +91,8 @@ class NullRecorder:
     spans: Optional[SpanLog] = None
     trace: Optional[TraceBuffer] = None
 
-    def now(self) -> float:
-        return 0.0
-
-    @staticmethod
-    def fence(x):
-        return x
-
-    def timed(self, name, stats=None, key=None, track=None, **args):
-        return _NullTimed(stats, key)
-
-    def slice(self, name, start_s, end_s=None, track=None, **args):
-        pass
+    def span(self, name, stats=None):
+        return span(name, stats)
 
     def instant(self, name, track="events", **args):
         pass
@@ -120,55 +113,13 @@ class NullRecorder:
 NULL_RECORDER = NullRecorder()
 
 
-class _Timed:
-    """Fenced timed section: stats accumulation + histogram + trace slice."""
-
-    __slots__ = ("_rec", "_name", "_stats", "_key", "_track", "_args",
-                 "_t0")
-
-    def __init__(self, rec, name, stats, key, track, args):
-        self._rec = rec
-        self._name = name
-        self._stats = stats
-        self._key = key
-        self._track = track
-        self._args = args
-
-    def __enter__(self):
-        self._t0 = self._rec.now()
-        return self
-
-    def fence(self, x):
-        return fence(x)
-
-    def set(self, **kw) -> None:
-        self._args.update(kw)
-
-    def __exit__(self, *exc):
-        rec = self._rec
-        end = rec.now()
-        elapsed = end - self._t0
-        if self._stats is not None and self._key is not None:
-            self._stats[self._key] += elapsed
-        if rec.registry is not None:
-            rec.registry.histogram(
-                f"{self._name}_seconds",
-                help=f"fenced wall-clock of {self._name} sections",
-            ).observe(elapsed)
-        if rec.trace is not None:
-            rec.trace.slice(self._name, self._t0, end,
-                            track=self._track, **self._args)
-        return False
-
-
 class Recorder:
     """Live recorder: registry + request spans + Perfetto trace.
 
     Any of the three sinks can be switched off at construction
     (``spans=False`` / ``trace=False``); pre-built instances can also be
-    passed in (e.g. a SpanLog with an injected test clock).  All engine
-    hooks are cheap host-side bookkeeping; the only interaction with JAX
-    is the explicit ``fence`` inside timed sections.
+    passed in (e.g. a SpanLog with an injected test clock).  Every hook
+    is host-side bookkeeping; nothing here waits for the device.
     """
 
     enabled = True
@@ -182,26 +133,19 @@ class Recorder:
         if trace is True:
             trace = TraceBuffer()
         self.trace = trace or None
-        self._t0 = time.perf_counter()
+        # slices are stamped relative to the trace buffer's start
+        self._t0 = self.trace.t0 if self.trace is not None else 0.0
 
-    def now(self) -> float:
-        """Seconds since recorder start — the shared slice/trace clock."""
+    def span(self, name, stats=None):
+        return span(name, stats, self)
+
+    def on_span(self, name: str, t0: float, t1: float) -> None:
+        """A closed span: its histogram and its Perfetto slice."""
+        self.registry.histogram(
+            f"{name}_seconds", help=f"host wall-clock of {name} spans",
+        ).observe(t1 - t0)
         if self.trace is not None:
-            return self.trace.now()
-        return time.perf_counter() - self._t0
-
-    @staticmethod
-    def fence(x):
-        return fence(x)
-
-    def timed(self, name, stats=None, key=None, track=None, **args):
-        return _Timed(self, name, stats, key, track, args)
-
-    def slice(self, name, start_s, end_s=None, track=None, **args):
-        if self.trace is not None:
-            if end_s is None:
-                end_s = self.trace.now()
-            self.trace.slice(name, start_s, end_s, track=track, **args)
+            self.trace.slice(name, t0 - self._t0, t1 - self._t0)
 
     def instant(self, name, track="events", **args):
         if self.trace is not None:

@@ -2,9 +2,9 @@
 
 The engine emits two event shapes:
 
-  * **slices** — timed sections ("step", "prefill", "prefill_chunk",
-    "decode") become complete events (``ph="X"``) with microsecond
-    ``ts``/``dur``;
+  * **slices** — the engine's spans ("serve.step", "serve.decode",
+    "serve.fetch", ...; :class:`repro.obs.span`) become complete events
+    (``ph="X"``) with microsecond ``ts``/``dur``;
   * **instants** — point events ("preempt", "restart", "fault_kill",
     "snapshot", "prefix_cow", "kv_handoff") become ``ph="i"`` markers.
 
@@ -34,14 +34,14 @@ class TraceBuffer:
     """Accumulates trace events; ``to_json()``/``save()`` export them."""
 
     def __init__(self, process_name: str = "repro.serve"):
-        self._t0 = time.perf_counter()
+        self.t0 = time.perf_counter()
         self.events: list[dict] = []
         self._tids: dict[str, int] = {}
         self.process_name = process_name
 
     def now(self) -> float:
         """Wall seconds since trace start (the slice clock)."""
-        return time.perf_counter() - self._t0
+        return time.perf_counter() - self.t0
 
     def _tid(self, track: str) -> int:
         tid = self._tids.get(track)

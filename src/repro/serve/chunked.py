@@ -132,7 +132,7 @@ class ChunkedPrefillState:
     chunk: int
     tokens: Optional[np.ndarray] = None   # default: req.prompt
     pos: int = 0                   # tokens already fed
-    logits: Optional[np.ndarray] = None   # last-valid-row logits, final chunk
+    logits: Any = None             # last-valid-row logits (device), final chunk
 
     def __post_init__(self):
         if self.tokens is None:
@@ -160,34 +160,25 @@ class ChunkedPrefillState:
             piece = np.concatenate([piece, pad], axis=0)
         return piece[None], start, n_valid
 
-    def advance(self, n_valid: int, cache: Any,
-                logits: Optional[np.ndarray]) -> None:
+    def advance(self, n_valid: int, cache: Any, logits: Any) -> None:
         self.pos += n_valid
         self.cache = cache
         if logits is not None:
             self.logits = logits
 
 
-def run_one_chunk(state: ChunkedPrefillState, params, chunk_fn,
-                  fence=None) -> int:
-    """Feed one chunk of ``state`` through ``chunk_fn`` (a jitted
+def run_one_chunk(state: ChunkedPrefillState, params, chunk_fn) -> int:
+    """Dispatch one chunk of ``state`` through ``chunk_fn`` (a jitted
     ``model.prefill_chunk``).  Returns the number of prompt tokens fed.
 
-    ``fence``: optional callable applied to the updated cache before
-    returning.  Non-final chunks materialize nothing on the host (the
-    logits stay on-device as ``None``), so without a fence a wall-clock
-    around this call times only XLA *dispatch*; the engines' recorder
-    passes its ``block_until_ready`` fence here so timed chunk sections
-    cover the compute.
+    Nothing is read back: the final chunk leaves its logits on the device
+    in ``state.logits`` for the engine to fetch.
     """
     tokens, start, n_valid = state.next_chunk()
     logits, cache = chunk_fn(
         params, {"tokens": jnp.asarray(tokens)}, state.cache,
         jnp.int32(start), jnp.int32(n_valid),
     )
-    if fence is not None:
-        fence(cache)
     will_finish = start + n_valid >= state.total
-    state.advance(n_valid, cache,
-                  np.asarray(logits) if will_finish else None)
+    state.advance(n_valid, cache, logits if will_finish else None)
     return n_valid

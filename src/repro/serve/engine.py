@@ -40,6 +40,7 @@ complete the failure story.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Optional
 
 import jax
@@ -84,6 +85,9 @@ class Request:
     preemptions: int = 0             # pool-pressure evictions survived
     restarts: int = 0                # fault kills survived (prefix discarded)
     error: Optional[RequestError] = None   # set on FAILED / EXPIRED
+    # host clock when the request last joined the queue (submit, or the
+    # re-queue after a preemption): read at admission for queue_wait_s
+    queued_at: float = dataclasses.field(default_factory=time.perf_counter)
 
     @property
     def prompt_len(self) -> int:
@@ -140,16 +144,19 @@ class ServingEngine:
         self.finished: dict[int, Request] = {}
         self._next_rid = 0
         self._clock = 0                 # engine step clock (deadline basis)
-        # observability: NULL_RECORDER (no registry, unfenced legacy
-        # timings, every hook a no-op) unless the caller attaches a
-        # repro.obs.Recorder.  ``stats`` stays a real dict — EngineStats
-        # mirrors writes into the recorder's metrics registry when one
-        # is attached and is a plain dict otherwise.
+        # observability: NULL_RECORDER (bare spans, every other hook a
+        # no-op) unless the caller attaches a repro.obs.Recorder.
+        # ``stats`` stays a real dict — EngineStats mirrors writes into the
+        # recorder's metrics registry when one is attached and is a plain
+        # dict otherwise.  Each span adds ``<phase>_s``/``<phase>_calls``.
         self._obs = recorder if recorder is not None else NULL_RECORDER
         self.stats = EngineStats(self._obs.registry, {
             "steps": 0, "prefill_calls": 0, "decode_steps": 0,
             "prompt_tokens": 0, "generated_tokens": 0, "wasted_row_steps": 0,
-            "prefill_time_s": 0.0, "decode_time_s": 0.0,
+            # blocking device-to-host reads: logits fetches and samples
+            "device_syncs": 0,
+            # submit (or re-queue) to admission, summed over admissions
+            "queue_wait_s": 0.0, "admissions": 0,
             # robustness counters (lifecycle / preemption / faults)
             "rejected": 0, "cancelled": 0, "expired": 0, "failed": 0,
             "finished": 0,
@@ -244,12 +251,31 @@ class ServingEngine:
             (1,) + req.prompt.shape[1:]
         )
 
+    def _span(self, name: str):
+        """A ``repro.obs`` span of this engine, counted in ``stats``."""
+        return self._obs.span(name, self.stats)
+
+    def _fetch(self, logits) -> np.ndarray:
+        """Blocking read of a program's logits to the host."""
+        with self._span("serve.fetch"):
+            out = np.asarray(logits)
+        self.stats["device_syncs"] += 1
+        return out
+
     def _sample(self, req: Request, logits_row: np.ndarray) -> None:
+        # greedy and stochastic sampling each round-trip through the device
         tok = sample_token(logits_row, req.sampling, request_salt=req.rid,
                            step=len(req.generated))
+        self.stats["device_syncs"] += 1
         req.generated.append(tok)
         self.stats["generated_tokens"] += 1
         self._obs.on_token(req, self._clock)
+
+    def _admit(self, req: Request) -> None:
+        """QUEUED -> PREFILLING, counting the request's wait in the queue."""
+        self._transition(req, PREFILLING)
+        self.stats["queue_wait_s"] += time.perf_counter() - req.queued_at
+        self.stats["admissions"] += 1
 
     def _mark_finished(self, req: Request) -> None:
         self.finished[req.rid] = req
@@ -403,7 +429,9 @@ class ContinuousEngine(ServingEngine):
                           prefill_chunks=0, decode_row_steps=0,
                           prefix_hits=0, prefix_hit_tokens=0,
                           prefix_misses=0, prefix_evictions=0,
-                          prefix_cow_copies=0, shared_prefills=0)
+                          prefix_cow_copies=0, shared_prefills=0,
+                          # serve.step time of the steps that fed a chunk
+                          chunk_step_s=0.0, chunk_steps=0)
 
     # -- hooks the sharded engines override ------------------------------------------
     def _make_kv(self, n_blocks: int) -> PagedKVCache:
@@ -600,36 +628,56 @@ class ContinuousEngine(ServingEngine):
 
     # -- steps -----------------------------------------------------------------------
     def step(self) -> list[Request]:
-        """One engine tick: faults, expiry, admit+prefill, batched decode."""
+        """One engine tick: faults, expiry, admit+prefill, batched decode.
+
+        Spans (``repro.obs.span``): ``serve.step`` around the tick and,
+        inside it, the phases ``serve.admit``, ``serve.prefill_full`` (single-
+        shot prefill), ``serve.prefill_chunk``, ``serve.pages`` (the eager
+        page moves), ``serve.decode``, ``serve.fetch``, ``serve.sample`` and
+        ``serve.finish``.  Phases never nest, so their ``<phase>_s``
+        counters sum to ``step_s``; page resets of a request preempted or
+        expired during admission or decode growth count in that phase.
+        """
         finished: list[Request] = []
-        t_step = self._obs.now()
-        paused = False
-        if self._injector is not None:
-            paused = self._injector.begin_step(self, self._clock)
-        self._expire(finished)
-        admitted = chunks = decoded = 0
-        if not paused:
-            batch = self.scheduler.admit(self._clock)
-            for req in batch:
-                # claim the whole batch BEFORE any prefill runs: pinned
-                # prefix blocks can't be evicted by an earlier admittee's
-                # allocation pressure, so every claim matches at least
-                # what the admission probe reserved against
-                admitted += 1
-                self._transition(req, PREFILLING)
-                if self.prefix is not None:
-                    self._claim_prefix(req)
-            for req in batch:
-                if req.slot is None:
-                    continue   # preempted by an earlier admittee's prefill
+        step_span = self._span("serve.step")
+        with step_span:
+            with self._span("serve.admit"):
+                paused = False
+                if self._injector is not None:
+                    paused = self._injector.begin_step(self, self._clock)
+                self._expire(finished)
+                batch = [] if paused else self.scheduler.admit(self._clock)
+                for req in batch:
+                    # claim the whole batch BEFORE any prefill runs: pinned
+                    # prefix blocks can't be evicted by an earlier admittee's
+                    # allocation pressure, so every claim matches at least
+                    # what the admission probe reserved against
+                    self._admit(req)
+                    if self.prefix is not None:
+                        self._claim_prefix(req)
                 if self.prefill_chunk > 0:
-                    self._begin_chunked(req)
-                else:
-                    self._prefill_request(req)
-                    if req.slot is not None and req.done:
-                        self._finish(req, finished)
-            chunks = self._run_prefill_chunk(finished)
-            decoded = self._decode_batch(finished)
+                    for req in batch:
+                        # slot None: preempted by an earlier admittee
+                        if req.slot is not None:
+                            self._begin_chunked(req)
+            chunks = decoded = 0
+            if not paused:
+                if self.prefill_chunk <= 0:
+                    for req in batch:
+                        if req.slot is not None:
+                            self._prefill_request(req, finished)
+                chunks = self._run_prefill_chunk(finished)
+                decoded = self._decode_batch(finished)
+            with self._span("serve.finish"):
+                self._end_step(len(batch), chunks, decoded, finished, paused)
+        if chunks:
+            self.stats["chunk_step_s"] += step_span.elapsed
+            self.stats["chunk_steps"] += 1
+        return finished
+
+    def _end_step(self, admitted: int, chunks: int, decoded: int,
+                  finished: list[Request], paused: bool) -> None:
+        """Step bookkeeping: trace row, pool counters, gauges, watchdog."""
         self.step_trace.append({"admitted": admitted,
                                 "prefill_chunks": chunks,
                                 "decode_rows": decoded})
@@ -648,12 +696,8 @@ class ContinuousEngine(ServingEngine):
             for k, v in self.scheduler.occupancy().items():
                 reg.gauge(f"sched_{k}").set(v)
             reg.gauge("pool_allocated_blocks").set(na)
-            self._obs.slice("step", t_step, track="step", step=self._clock,
-                            admitted=admitted, chunks=chunks,
-                            decoded=decoded, finished=len(finished))
         self._watchdog(admitted + chunks + decoded + len(finished), paused)
         self._clock += 1
-        return finished
 
     # -- lifecycle: expiry / cancellation / preemption ---------------------------------
     def _terminate(self, req: Request, state: str,
@@ -729,6 +773,7 @@ class ContinuousEngine(ServingEngine):
             self._mark_finished(req)
             return
         self._transition(req, QUEUED)
+        req.queued_at = time.perf_counter()
         req.not_before = self._clock + 1 + \
             self.preempt_backoff * (2 ** min(retries - 1, 6))
         self.scheduler.requeue(req)
@@ -816,7 +861,8 @@ class ContinuousEngine(ServingEngine):
             diag,
         )
 
-    def _prefill_request(self, req: Request) -> None:
+    def _prefill_request(self, req: Request,
+                         finished: list[Request]) -> None:
         """Reference prefill at the exact prefill length, then page it.
 
         For a fresh request that is the prompt; for a preempted one it is
@@ -831,66 +877,38 @@ class ContinuousEngine(ServingEngine):
         written page span."""
         L = req.prefill_len
         nb = self.kv.blocks_for(L)
-        got = self._ensure_blocks(req, nb - req.n_shared)
+        shared = req.n_shared > 0 or req.cow_src is not None
+        with self._span("serve.admit"):
+            got = self._ensure_blocks(req, nb - req.n_shared)
+            if got is not None:
+                req.blocks = req.blocks + got
+                cache = self.model.init_cache(
+                    1, nb * self.page if shared else L, self.cache_dtype,
+                    full_length=True)
+                if shared:
+                    cache, start, span = self._gather_prefix(req, cache)
+                    cache = mask_cache_rows(cache, start, span)
         if got is None:
             return   # req itself was preempted under pool pressure
-        req.blocks = req.blocks + got
-        fed = L
-        if req.n_shared == 0 and req.cow_src is None:
-            cache = self.model.init_cache(1, L, self.cache_dtype,
-                                          full_length=True)
-            # with a live recorder, tm.fence(cache) blocks until the whole
-            # prefill program ran — np.asarray(logits) alone only forces
-            # the logits output, so the bare perf_counter delta of the
-            # legacy (null-recorder) path measures dispatch + partial
-            # compute, not the prefill
-            with self._obs.timed("prefill", self.stats, "prefill_time_s",
-                                 rid=req.rid, tokens=L,
-                                 step=self._clock) as tm:
+        with self._span("serve.prefill_full"):
+            if shared:
+                suffix = np.asarray(req.prefill_tokens)[start:]
+                fed = L - start
+                logits, cache = self._chunk(
+                    self.prefill_params, {"tokens": jnp.asarray(suffix[None])},
+                    cache, jnp.int32(start), jnp.int32(fed),
+                )
+            else:
+                fed = L
                 logits, cache = self._prefill(
                     self.prefill_params,
                     {"tokens": jnp.asarray(req.prefill_tokens[None])},
                     cache
                 )
-                logits = np.asarray(logits)
-                tm.fence(cache)
-            self.kv.write_pages(
-                self._handoff(pack_prefill_pages(cache, nb, self.page)),
-                req.blocks,
-            )
-        else:
-            cache = self.model.init_cache(1, nb * self.page,
-                                          self.cache_dtype,
-                                          full_length=True)
-            cache, start, span = self._gather_prefix(req, cache)
-            cache = mask_cache_rows(cache, start, span)
-            suffix = np.asarray(req.prefill_tokens)[start:]
-            fed = L - start
-            with self._obs.timed("prefill", self.stats, "prefill_time_s",
-                                 rid=req.rid, tokens=fed, shared=True,
-                                 step=self._clock) as tm:
-                logits, cache = self._chunk(
-                    self.prefill_params, {"tokens": jnp.asarray(suffix[None])},
-                    cache, jnp.int32(start), jnp.int32(fed),
-                )
-                logits = np.asarray(logits)
-                tm.fence(cache)
-            self.kv.write_pages(
-                self._handoff(pack_prefill_pages(
-                    slice_cache(cache, req.n_shared * self.page,
-                                nb * self.page),
-                    nb - req.n_shared, self.page,
-                )),
-                req.blocks[req.n_shared:],
-            )
-        if self.prefix is not None:
-            self._insert_prefix(req)
         if req.generated:
             self.stats["resumed_prefills"] += 1
-        self._sample(req, logits[0])
-        self._transition(req, DECODING)
-        self.stats["prefill_calls"] += 1
         self.stats["prompt_tokens"] += fed
+        self._land_prefill(req, cache, logits, finished)
 
     # -- chunked prefill ---------------------------------------------------------------
     def _begin_chunked(self, req: Request) -> None:
@@ -927,47 +945,64 @@ class ContinuousEngine(ServingEngine):
 
     def _run_prefill_chunk(self, finished: list[Request]) -> int:
         """Feed at most ONE chunk (of the oldest in-flight prefill) per
-        step — the bound the step-trace test asserts.  On the final chunk,
-        trim the temp cache to the request's block span, scatter it into
-        the page pools, and sample the first token from the chunk logits.
-        """
+        step — the bound the step-trace test asserts.  After the final
+        chunk the request's prefill lands (``_land_prefill``)."""
         if not self._prefilling:
             return 0
         rid = next(iter(self._prefilling))   # dict preserves FCFS order
         state = self._prefilling[rid]
-        # non-final chunks materialize nothing — the bare perf_counter
-        # delta here was the purest form of the dispatch-timing bug, so
-        # the recorder's fence goes *into* run_one_chunk
-        with self._obs.timed("prefill_chunk", self.stats, "prefill_time_s",
-                             rid=rid, pos=state.pos,
-                             step=self._clock) as tm:
-            fed = run_one_chunk(state, self.prefill_params, self._chunk,
-                                fence=tm.fence if self._obs.enabled else None)
-        self.stats["prefill_chunks"] += 1
-        self.stats["prompt_tokens"] += fed
+        with self._span("serve.prefill_chunk"):
+            fed = run_one_chunk(state, self.prefill_params, self._chunk)
+            self.stats["prefill_chunks"] += 1
+            self.stats["prompt_tokens"] += fed
         if state.done:
             del self._prefilling[rid]
-            req = state.req
-            nb = len(req.blocks)
-            n_sh = req.n_shared
+            self._land_prefill(state.req, state.cache, state.logits, finished)
+        return 1
+
+    def _land_prefill(self, req: Request, cache, logits,
+                      finished: list[Request]) -> None:
+        """Finish a prefill: read its last-row logits, trim the temp cache
+        to the request's private block span and scatter it into the page
+        pools, index the prompt pages, sample the first token."""
+        logits = self._fetch(logits)
+        nb = len(req.blocks)
+        n_sh = req.n_shared
+        with self._span("serve.pages"):
             self.kv.write_pages(
                 self._handoff(pack_prefill_pages(
-                    slice_cache(state.cache, n_sh * self.page,
-                                nb * self.page),
+                    slice_cache(cache, n_sh * self.page, nb * self.page),
                     nb - n_sh, self.page
                 )),
                 req.blocks[n_sh:],
             )
             if self.prefix is not None:
                 self._insert_prefix(req)
-            self._sample(req, state.logits[0])
+        with self._span("serve.sample"):
+            self._sample(req, logits[0])
+        with self._span("serve.finish"):
             self._transition(req, DECODING)
             self.stats["prefill_calls"] += 1
-            if req.done:
-                self._finish(req, finished)
-        return 1
+        if req.done:
+            self._finish(req, finished)
 
     def _decode_batch(self, finished: list[Request]) -> int:
+        with self._span("serve.decode"):
+            logits, active = self._dispatch_decode()
+        if not active:
+            return 0
+        logits = self._fetch(logits)
+        with self._span("serve.sample"):
+            for r in active:
+                self._sample(r, logits[r.slot])
+        for r in active:
+            if r.done:
+                self._finish(r, finished)
+        return len(active)
+
+    def _dispatch_decode(self):
+        """Grow blocks, build the batch and dispatch one paged decode step.
+        Returns (device logits, rows) — (None, []) with no row to decode."""
         # sorted by rid: deterministic row layout whatever the admission
         # interleaving was (cross-role reproducibility for disaggregation);
         # rows still mid-prefill have no sampled token yet and are skipped
@@ -976,8 +1011,6 @@ class ContinuousEngine(ServingEngine):
              if not r.done and r.rid not in self._prefilling),
             key=lambda r: r.rid,
         )
-        if not active:
-            return 0
         for r in active:
             if r.slot is None:
                 continue   # preempted while growing an earlier row
@@ -991,7 +1024,7 @@ class ContinuousEngine(ServingEngine):
         # growth may have evicted rows (theirs or later ones): re-filter
         active = [r for r in active if r.slot is not None]
         if not active:
-            return 0
+            return None, []
         B = self.max_slots
         tok_shape = (B, 1) + active[0].prompt.shape[1:]
         tokens = np.zeros(tok_shape, np.int32)
@@ -1002,30 +1035,24 @@ class ContinuousEngine(ServingEngine):
             positions[r.slot] = r.input_pos
             bt_rows[r.slot] = r.blocks
         bt = self.kv.block_table(bt_rows, self.max_blocks)
-        with self._obs.timed("decode", self.stats, "decode_time_s",
-                             rows=len(active), step=self._clock) as tm:
-            logits, self.kv.pools = self._decode(
-                self.params, jnp.asarray(tokens), self.kv.pools,
-                jnp.asarray(bt), jnp.asarray(positions),
-            )
-            logits = np.asarray(logits)
-            tm.fence(self.kv.pools)
+        logits, self.kv.pools = self._decode(
+            self.params, jnp.asarray(tokens), self.kv.pools,
+            jnp.asarray(bt), jnp.asarray(positions),
+        )
         self.stats["decode_steps"] += 1
         self.stats["decode_row_steps"] += len(active)
-        for r in active:
-            self._sample(r, logits[r.slot])
-            if r.done:
-                self._finish(r, finished)
-        return len(active)
+        return logits, active
 
     def _finish(self, req: Request, finished: list[Request]) -> None:
         """Evict: release every block the request held (pages the index
         or another reader still references stay resident)."""
-        self._release_request_blocks(req)
-        self.scheduler.finish(req)
-        self._transition(req, FINISHED)
-        self._mark_finished(req)
-        finished.append(req)
+        with self._span("serve.pages"):
+            self._release_request_blocks(req)
+        with self._span("serve.finish"):
+            self.scheduler.finish(req)
+            self._transition(req, FINISHED)
+            self._mark_finished(req)
+            finished.append(req)
 
 
 class StaticEngine(ServingEngine):
@@ -1078,42 +1105,40 @@ class StaticEngine(ServingEngine):
         cache = self.model.init_cache(B, S + max_gen, self.cache_dtype)
         prompts = np.stack([r.prompt for r in group])
         for r in group:
-            self._transition(r, PREFILLING)
-        with self._obs.timed("prefill", self.stats, "prefill_time_s",
-                             batch=B, tokens=B * S,
-                             step=self._clock) as tm:
+            self._admit(r)
+        with self._span("serve.prefill_full"):
             logits, cache = self._prefill(
                 self.params, {"tokens": jnp.asarray(prompts)}, cache
             )
-            logits = np.asarray(logits)
-            tm.fence(cache)
-        for i, r in enumerate(group):
-            self._sample(r, logits[i])
+        logits = self._fetch(logits)
+        with self._span("serve.sample"):
+            for i, r in enumerate(group):
+                self._sample(r, logits[i])
+        for r in group:
             self._transition(r, DECODING)
         self.stats["prefill_calls"] += 1
         self.stats["prompt_tokens"] += B * S
         for step_i in range(1, max_gen):
             nxt = np.stack([self._next_input(r) for r in group])
-            with self._obs.timed("decode", self.stats, "decode_time_s",
-                                 rows=B, step=self._clock) as tm:
+            with self._span("serve.decode"):
                 logits, cache = self._decode(
                     self.params, jnp.asarray(nxt), cache,
                     jnp.int32(S + step_i - 1),
                 )
-                logits = np.asarray(logits)
-                tm.fence(cache)
+            logits = self._fetch(logits)
             self.stats["decode_steps"] += 1
             self.stats["cache_slot_steps"] += B * (S + max_gen)
             self.stats["live_token_steps"] += sum(
                 min(r.input_pos + 1, r.prompt_len + r.max_new_tokens)
                 for r in group
             )
-            for i, r in enumerate(group):
-                if r.done:
-                    # lockstep: the row keeps burning the step anyway
-                    self.stats["wasted_row_steps"] += 1
-                else:
-                    self._sample(r, logits[i])
+            with self._span("serve.sample"):
+                for i, r in enumerate(group):
+                    if r.done:
+                        # lockstep: the row keeps burning the step anyway
+                        self.stats["wasted_row_steps"] += 1
+                    else:
+                        self._sample(r, logits[i])
         for r in group:
             self._transition(r, FINISHED)
             self._mark_finished(r)

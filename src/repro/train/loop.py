@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import TrainConfig
+from repro.obs import span
 from repro.utils import merge_trees, split_trainable
 from .checkpoint import CheckpointManager
 from .compress import compress_decompress, init_error_feedback
@@ -115,7 +116,8 @@ def make_train_step(
             gnorm = jnp.zeros(())
 
         lr = sched(state.step)
-        new_params, new_opt = opt.update(g, state.opt_state, train, lr)
+        with jax.named_scope("optim.update"):
+            new_params, new_opt = opt.update(g, state.opt_state, train, lr)
         new_state = TrainState(
             params=new_params,
             static=static,
@@ -158,6 +160,8 @@ class Trainer:
         # straggler watchdog: EMA of step time; steps > 3x EMA are flagged
         self._ema: Optional[float] = None
         self.straggler_events: list[tuple[int, float]] = []
+        # <phase>_s / <phase>_calls of the train.* spans in run()
+        self.stats: dict = {}
 
     # -- resume ------------------------------------------------------------
     def try_resume(self) -> Optional[int]:
@@ -199,11 +203,15 @@ class Trainer:
             for i in range(start, start + n_steps):
                 if fail_at_step is not None and i == fail_at_step:
                     raise RuntimeError(f"simulated node failure at step {i}")
-                batch = jax.tree_util.tree_map(jnp.asarray, next(self.data))
+                with span("train.batch", self.stats):
+                    batch = jax.tree_util.tree_map(jnp.asarray,
+                                                   next(self.data))
                 t0 = time.perf_counter()
-                self.state, metrics = self.step_fn(
-                    self.state, self._shape_batch(batch))
-                metrics = {k: float(v) for k, v in metrics.items()}
+                with span("train.dispatch", self.stats):
+                    self.state, metrics = self.step_fn(
+                        self.state, self._shape_batch(batch))
+                with span("train.fetch", self.stats):
+                    metrics = {k: float(v) for k, v in metrics.items()}
                 dt = time.perf_counter() - t0
                 if self._ema is None:
                     self._ema = dt

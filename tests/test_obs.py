@@ -3,7 +3,7 @@
 Covers the metrics registry + Prometheus rendering, the EngineStats
 compatibility shim, nearest-rank percentile math, the SpanLog state
 machine (driven by a fake clock), the Perfetto trace buffer + validator,
-the async-dispatch fence regression, and the kernelstats roofline table.
+the span primitive (no fence), and the kernelstats roofline table.
 """
 import json
 import time
@@ -273,7 +273,7 @@ def test_trace_monotonicity_is_per_track():
     validate_trace(buf.to_json())
 
 
-# -- recorder: fenced timing (the async-dispatch satellite) -------------------------
+# -- spans: the one timing primitive ------------------------------------------------
 
 
 class _AsyncResult:
@@ -288,48 +288,50 @@ class _AsyncResult:
         return self
 
 
-def test_fenced_timing_covers_async_work():
-    """Regression for the dispatch-timing bug: an un-fenced perf_counter
-    section around an async dispatch measures ~0, the recorder's fenced
-    section measures the actual device time."""
+def test_span_times_host_work_and_never_fences():
+    """A span around a dispatch times the host's part only: it waits for
+    nothing (the device's time comes from the profiler), with or without
+    a recorder; host work inside it is counted."""
     work = 0.05
     rec = Recorder(spans=False, trace=False)
-    stats_fenced = {"t": 0.0}
-    with rec.timed("prefill", stats_fenced, "t") as tm:
-        tm.fence(_AsyncResult(work))          # what the engine does
-    stats_null = {"t": 0.0}
-    with NULL_RECORDER.timed("prefill", stats_null, "t") as tm:
-        _AsyncResult(work)                     # dispatch returns instantly
-        tm.fence(None)                         # null fence: identity no-op
-    assert stats_fenced["t"] >= 0.9 * work, stats_fenced
-    assert stats_null["t"] <= 0.5 * work, stats_null
-    # the fenced section also landed in the <name>_seconds histogram
-    snap = rec.registry.snapshot()["prefill_seconds"]
-    assert snap["count"] == 1 and snap["sum"] >= 0.9 * work
+    for r in (rec, NULL_RECORDER):
+        st = {}
+        with r.span("serve.decode", st):
+            _AsyncResult(work)                 # dispatch returns at once
+        assert st["decode_s"] <= 0.5 * work, st
+        with r.span("serve.decode", st):
+            time.sleep(0.01)                   # host work
+        assert st["decode_s"] >= 0.01 and st["decode_calls"] == 2
+    snap = rec.registry.snapshot()["serve.decode_seconds"]
+    assert snap["count"] == 2 and snap["sum"] >= 0.01
 
 
-def test_fence_walks_pytrees_and_tolerates_plain_leaves():
-    from repro.obs import fence
+def test_span_keys_follow_the_phase_name():
+    from repro.obs import span
 
-    calls = []
-
-    class Leaf:
-        def block_until_ready(self):
-            calls.append(1)
-
-    tree = {"a": Leaf(), "b": [Leaf(), 3, "x"], "c": None}
-    assert fence(tree) is tree
-    assert len(calls) == 2
+    st = {"step_s": 1.0}
+    with span("serve.step", st) as outer:
+        with span("serve.prefill_chunk", st):
+            pass
+        with span("train.fetch", None):        # annotation only
+            pass
+    assert set(st) == {"step_s", "step_calls", "prefill_chunk_s",
+                       "prefill_chunk_calls"}
+    assert st["step_calls"] == 1 and st["prefill_chunk_calls"] == 1
+    assert st["step_s"] == pytest.approx(1.0 + outer.elapsed)
+    assert outer.elapsed >= st["prefill_chunk_s"]
+    with span("plain", st):
+        pass
+    assert st["plain_calls"] == 1
 
 
 def test_null_recorder_is_inert_and_preserves_stats_accumulation():
     assert NULL_RECORDER.enabled is False
     assert NULL_RECORDER.registry is None
-    st = {"prefill_time_s": 0.0}
-    with NULL_RECORDER.timed("prefill", st, "prefill_time_s") as tm:
+    st = {"prefill_s": 0.0}
+    with NULL_RECORDER.span("serve.prefill", st):
         time.sleep(0.002)
-        tm.set(rid=1)                          # all hooks accept-and-ignore
-    assert st["prefill_time_s"] > 0
+    assert st["prefill_s"] > 0 and st["prefill_calls"] == 1
     NULL_RECORDER.on_submit(_FakeReq(0), 0)
     NULL_RECORDER.instant("preempt", rid=0)
     NULL_RECORDER.annotate(0, x=1)
@@ -337,14 +339,15 @@ def test_null_recorder_is_inert_and_preserves_stats_accumulation():
 
 def test_recorder_timed_emits_slice_and_instant_counts():
     rec = Recorder()
-    with rec.timed("decode", track="decode", rows=3):
+    with rec.span("serve.decode"):
         pass
     rec.instant("preempt", rid=2)
     doc = rec.trace.to_json()
     slices = [e for e in doc["traceEvents"] if e["ph"] == "X"]
-    assert slices and slices[0]["name"] == "decode"
-    assert slices[0]["args"] == {"rows": 3}
+    assert slices and slices[0]["name"] == "serve.decode"
+    assert slices[0]["ts"] >= 0 and slices[0]["dur"] >= 0
     assert rec.registry.snapshot()["event_preempt_total"] == 1
+    assert "serve_decode_seconds_count 1" in rec.registry.render_prometheus()
     validate_trace(doc)
 
 
