@@ -269,13 +269,37 @@ class RBGP4Layout:
         """perm such that WdataT.flat = Wdata.flat[perm].
 
         Both compact layouts enumerate the same nnz set; the permutation maps
-        the transposed layout's slot order to the forward layout's.  Static
-        per layer; used by the Pallas backward pass (dI kernel).
+        the transposed layout's slot order to the forward layout's.  Value by
+        value, what :meth:`transpose_block_perm` does by whole blocks; the
+        tests' reference for it.
         """
         return _slot_transpose_perm(
             self._col_index(), self.transpose_layout()._col_index(),
             self.m, self.k,
         )
+
+    def transpose_block_perm(self) -> np.ndarray:
+        """(n_blocks,) int32: the forward block behind each transposed block.
+
+        ``G_r (x) G_b`` is complete, so every inner (G, C) block is dense and
+        the transposed layout holds the same blocks, each transposed, in
+        another order.  Blocks are numbered ``(uo, ui, ko, ki)`` forward
+        (tile-row, group-row, outer slot, inner slot) and ``(vo, vi, ko',
+        ki')`` transposed; transposed block ``(vo, vi, ko', ki')`` is the
+        forward block at ``uo = adj_o^T[vo, ko']``, ``ui = adj_i^T[vi, ki']``
+        and the slots where ``vo``, ``vi`` sit in ``adj_o[uo]``,
+        ``adj_i[ui]``.  O(n_blocks), built from the base graphs alone;
+        :meth:`transpose_perm` is the same map value by value.
+        """
+        sp = self.spec
+        lt = self.transpose_layout()
+        ko = _slot_of(self.adj_o, lt.adj_o)  # (V_o, d_o^T)
+        ki = _slot_of(self.adj_i, lt.adj_i)  # (V_i, d_i^T)
+        uo = lt.adj_o[:, None, :, None].astype(np.int64)
+        ui = lt.adj_i[None, :, None, :].astype(np.int64)
+        blk = ((uo * sp.g_i[0] + ui) * sp.d_o
+               + ko[:, None, :, None]) * sp.d_i + ki[None, :, None, :]
+        return blk.reshape(-1).astype(np.int32)
 
     # -- memory accounting (paper §4 + Table 1 'Mem' model) ------------------
     def memory_bytes(self, value_bytes: int = 4, index_bytes: int = 4) -> dict:
@@ -303,6 +327,14 @@ class RBGP4Layout:
             f"o={sp.g_o}@{sp.sp_o} i={sp.g_i}@{sp.sp_i} "
             f"G={sp.group_rows} C={sp.chunk_cols} TM={sp.tile_m} TK={sp.tile_k})"
         )
+
+
+def _slot_of(adj: np.ndarray, adj_t: np.ndarray) -> np.ndarray:
+    """For each edge ``(v, adj_t[v, j])`` of a graph's transpose, the slot
+    at which ``v`` sits in ``adj[adj_t[v, j]]``; shape of ``adj_t``."""
+    slot = np.full((adj.shape[0], adj_t.shape[0]), -1, np.int64)
+    slot[np.arange(adj.shape[0])[:, None], adj] = np.arange(adj.shape[1])
+    return slot[adj_t, np.arange(adj_t.shape[0])[:, None]]
 
 
 def _slot_transpose_perm(ci: np.ndarray, ci_t: np.ndarray,

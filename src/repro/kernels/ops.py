@@ -4,7 +4,8 @@
 
   * ``matmul(w_data, x)``  — O = W_s @ I with a custom VJP:
         dI = W_s^T @ dO     (same forward kernel, transposed layout; the
-                             compact transpose is a static permutation)
+                             compact transpose moves whole (G, C) blocks
+                             by a static permutation)
         dW = (dO @ I^T)|_m  (SDDMM kernel, directly in compact storage)
   * ``linear(x, w_data, bias=…, fuse=…, residual=…)`` — y = x @ W_s^T for
     (batch, K) activations (token-major layout used by the model code),
@@ -19,7 +20,7 @@
     experts (cloned-mask expert parallelism shares this op's adjacency),
     same epilogue + transpose-free VJP via the stacked kernels.
 
-Construction of the static kernel metadata (dims, transposed layout, slot
+Construction of the static kernel metadata (dims, transposed layout, block
 permutation) is memoized at module level — :func:`get_op` is the cached
 entry point the backend registry uses, so repeated ``sparse_linear`` calls
 under scan/jit never rebuild it per trace.
@@ -75,18 +76,6 @@ def compact_init(key: jax.Array, layout, *, lead: tuple = (),
     return (jax.random.normal(key, shape) * scale).astype(dtype)
 
 
-_PERM_CACHE: dict[tuple, np.ndarray] = {}
-
-
-def _transpose_perm_cached(layout) -> np.ndarray:
-    """Memoized transpose slot permutation (content-keyed)."""
-    key = layout_cache_key(layout)
-    perm = _PERM_CACHE.get(key)
-    if perm is None:
-        perm = _PERM_CACHE[key] = layout.transpose_perm()
-    return perm
-
-
 _OP_CACHE: dict[tuple, "RBGP4Op"] = {}
 
 
@@ -96,7 +85,7 @@ def get_op(layout, block_n="auto", interpret: Optional[bool] = None
 
     Every layer — and every re-trace of the same layer under jit/scan —
     sharing a spec (hence, by deterministic sampling, the same graphs)
-    reuses one op bundle (dims, transposed layout, permutation, VJP
+    reuses one op bundle (dims, transposed layout, block permutation, VJP
     closures).  The key includes the adjacency bytes, not just the spec,
     so a ``transpose_layout()`` product of a square spec can never collide
     with the forward layout (see ``layout_cache_key``).
@@ -129,7 +118,7 @@ class RBGP4Op:
         self.layout_t = lt
         self.dims_t = kernel_dims(lt)
         self.adj_o_t = np.asarray(lt.adj_o, np.int32)
-        self._t_perm = _transpose_perm_cached(layout)  # static permutation
+        self._t_blocks = layout.transpose_block_perm()  # static, per block
 
         self._matmul = self._build_matmul()
         # fused token-major linears, keyed (fuse, has_bias, has_residual);
@@ -137,22 +126,35 @@ class RBGP4Op:
         self._linear_cache: dict = {}
         self._stacked_cache: dict = {}
 
-    # -- transpose of the compact storage (static gather) -------------------
+    # -- transpose of the compact storage (static block gather) -------------
     def transpose_data(self, w_data: jax.Array) -> jax.Array:
-        """WdataT such that it packs W^T under the transposed layout."""
+        """WdataT such that it packs W^T under the transposed layout.
+
+        ``w_data`` is (..., M, nnz_row); leading dims (experts) ride along.
+        Moves whole dense (G, C) blocks (one bf16 tile at G=16, C=128) by
+        the layout's static block permutation and transposes each; the
+        values end where ``layout.transpose_perm()`` puts them.
+        """
+        sp = self.layout.spec
+        lead = w_data.shape[:-2]
+        g, c = sp.group_rows, sp.chunk_cols
         with jax.named_scope("rbgp4.transpose_data"):
-            perm = jnp.asarray(self._t_perm)
-            return jnp.take(w_data.reshape(-1), perm).reshape(
-                self.dims_t.m, -1)
+            # (..., n_o, U_i, G, d_o, d_i, C) -> (..., n_blocks, G, C)
+            blocks = jnp.moveaxis(
+                w_data.reshape(*lead, sp.g_o[0], sp.g_i[0], g,
+                               sp.d_o, sp.d_i, c), -4, -2,
+            ).reshape(*lead, -1, g, c)
+            blocks = jnp.take(blocks, jnp.asarray(self._t_blocks),
+                              axis=len(lead))
+            # the blocks of one band of C rows of W^T, stacked (nnz_col, C),
+            # transpose into that band: one 2-D transpose per band
+            bands = blocks.reshape(*lead, self.dims_t.m // c, -1, c)
+            return jnp.swapaxes(bands, -1, -2).reshape(
+                *lead, self.dims_t.m, -1)
 
     def transpose_data_stacked(self, w_data: jax.Array) -> jax.Array:
         """Per-expert transpose of stacked (E, M, nnz_row) compact values."""
-        e = w_data.shape[0]
-        with jax.named_scope("rbgp4.transpose_data"):
-            perm = jnp.asarray(self._t_perm)
-            return jnp.take(
-                w_data.reshape(e, -1), perm, axis=1
-            ).reshape(e, self.dims_t.m, -1)
+        return self.transpose_data(w_data)
 
     # -- forward/backward ----------------------------------------------------
     # named scopes (``rbgp4.fwd``, ``rbgp4.sddmm``, ``rbgp4.dx``,

@@ -1,4 +1,6 @@
 """Per-kernel allclose tests vs pure-jnp oracles (interpret mode on CPU)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -127,14 +129,85 @@ def test_op_linear_shapes_and_value():
     )
 
 
-def test_transpose_data_is_transpose():
-    lay = make_layout(64, 64, 0.5, 0.5, 4, 4, 4, 4, seed=17)
+class _ElementGatherOp(RBGP4Op):
+    """``RBGP4Op`` with the value-by-value transpose (one static gather of
+    nnz entries through ``transpose_perm()``), as the oracle."""
+
+    def transpose_data(self, w_data):
+        lead = w_data.shape[:-2]
+        perm = jnp.asarray(self.layout.transpose_perm())
+        return jnp.take(w_data.reshape(*lead, -1), perm, axis=-1).reshape(
+            *lead, self.dims_t.m, -1)
+
+
+# m, k, sp_o, sp_i, G, C, ui, vi
+TRANSPOSE_SWEEP = [
+    (64, 64, 0.5, 0.5, 4, 4, 4, 4),
+    (128, 64, 0.75, 0.0, 4, 8, 4, 2),
+    (64, 128, 0.0, 0.5, 8, 8, 2, 4),
+    (64, 64, 0.9375, 0.0, 2, 2, 2, 2),
+]
+
+
+@pytest.mark.parametrize("m,k,sp_o,sp_i,G,C,ui,vi", TRANSPOSE_SWEEP)
+def test_transpose_data_is_transpose(m, k, sp_o, sp_i, G, C, ui, vi):
+    lay = make_layout(m, k, sp_o, sp_i, G, C, ui, vi, seed=17)
     op = RBGP4Op(lay, interpret=True)
+    oracle = _ElementGatherOp(lay, interpret=True)
     w = rand(jax.random.PRNGKey(0), lay.data_shape, jnp.float32)
     wt = op.transpose_data(w)
     dense = lay.unpack(np.asarray(w))
     dense_t = op.layout_t.unpack(np.asarray(wt))
     np.testing.assert_array_equal(dense_t, dense.T)
+    # bit for bit the element gather, alone and per expert
+    np.testing.assert_array_equal(np.asarray(wt),
+                                  np.asarray(oracle.transpose_data(w)))
+    ws = rand(jax.random.PRNGKey(1), (3,) + lay.data_shape, jnp.bfloat16)
+    np.testing.assert_array_equal(
+        np.asarray(op.transpose_data_stacked(ws)),
+        np.asarray(oracle.transpose_data_stacked(ws)))
+
+
+@pytest.mark.parametrize("form", ["linear", "linear_stacked", "matmul"])
+def test_block_transpose_grads_match_element_gather(form):
+    """dx and dW through every RBGP4 backward are bit-identical to the
+    element-gather transpose's."""
+    lay = make_layout(128, 64, 0.75, 0.0, 4, 8, 4, 2, seed=19)
+    op = RBGP4Op(lay, interpret=True, block_n=16)
+    oracle = _ElementGatherOp(lay, interpret=True, block_n=16)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(11))
+    if form == "linear_stacked":
+        w = rand(k1, (2,) + lay.data_shape, jnp.float32)
+        x = rand(k2, (2, 8, lay.k), jnp.float32)
+    else:
+        w = rand(k1, lay.data_shape, jnp.float32)
+        x = rand(k2, (lay.k, 8) if form == "matmul" else (8, lay.k),
+                 jnp.float32)
+
+    def grads(o):
+        if form == "matmul":
+            f = lambda w, x: jnp.sum(jnp.sin(o.matmul(w, x)))
+        else:
+            f = lambda w, x: jnp.sum(jnp.sin(getattr(o, form)(x, w)))
+        return jax.grad(f, argnums=(0, 1))(w, x)
+
+    for a, b in zip(grads(op), grads(oracle)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_linear_backward_holds_no_nnz_sized_index():
+    """The backward's static transpose data is one int32 entry per (G, C)
+    block, never one per stored value."""
+    lay = make_layout(64, 64, 0.5, 0.5, 4, 4, 4, 4, seed=23)
+    op = RBGP4Op(lay, interpret=True, block_n=16)
+    w = jnp.ones(lay.data_shape, jnp.bfloat16)
+    x = jnp.ones((8, lay.k), jnp.bfloat16)
+    loss = lambda w, x: jnp.sum(op.linear(x, w).astype(jnp.float32))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(w, x).as_text()
+    n_blocks = lay.spec.nnz // (lay.spec.group_rows * lay.spec.chunk_cols)
+    sizes = [int(np.prod([int(d) for d in dims.split("x")]))
+             for dims in re.findall(r"tensor<([\dx]+)xi32>", text)]
+    assert sizes and max(sizes) <= n_blocks < lay.spec.nnz
 
 
 def test_kernel_under_jit_and_grad_accumulation():

@@ -83,6 +83,42 @@ def test_transpose_layout_and_perm():
     assert np.array_equal(lt.unpack(data_t), w.T)
 
 
+def _block_transpose(lay, w):
+    """W^T's compact values by whole (G, C) blocks, in numpy."""
+    sp, spt = lay.spec, lay.spec.transpose()
+    g, c = sp.group_rows, sp.chunk_cols
+    blocks = w.reshape(sp.g_o[0], sp.g_i[0], g, sp.d_o, sp.d_i, c)
+    blocks = np.moveaxis(blocks, 2, 4).reshape(-1, g, c)
+    blocks = blocks[lay.transpose_block_perm()]
+    bands = blocks.reshape(spt.m // c, -1, c)
+    return np.swapaxes(bands, 1, 2).reshape(spt.m, spt.nnz_per_row)
+
+
+@pytest.mark.parametrize(
+    "spec",
+    [
+        small_spec(seed=3),                                  # G = C = 4
+        RBGP4Spec(g_o=(8, 4), g_r=(2, 4), g_i=(4, 2), g_b=(2, 2),
+                  sp_o=0.75, sp_i=0.0, seed=1),              # G 4, C 8
+        RBGP4Spec(g_o=(2, 4), g_r=(8, 2), g_i=(2, 4), g_b=(1, 4),
+                  sp_o=0.0, sp_i=0.5, seed=2),               # G 8, C 8
+        RBGP4Spec(g_o=(4, 2), g_r=(1, 2), g_i=(8, 4), g_b=(2, 1),
+                  sp_o=0.5, sp_i=0.75, seed=4),              # G 2, C 2
+        design_rbgp4(1024, 5120, 0.75),                      # G 16, C 128
+    ],
+    ids=["g4c4", "g4c8", "g8c8", "g2c2", "nemo-1024x5120"],
+)
+def test_transpose_block_perm_matches_element_perm(spec):
+    lay = RBGP4Layout(spec)
+    perm = lay.transpose_block_perm()
+    n_blocks = spec.nnz // (spec.group_rows * spec.chunk_cols)
+    assert perm.shape == (n_blocks,) and perm.dtype == np.int32
+    assert np.array_equal(np.sort(perm), np.arange(n_blocks))  # one-to-one
+    w = np.arange(spec.nnz, dtype=np.int64).reshape(lay.data_shape)
+    want = w.reshape(-1)[lay.transpose_perm()]
+    assert np.array_equal(_block_transpose(lay, w).reshape(-1), want)
+
+
 def test_memory_accounting():
     lay = RBGP4Layout(small_spec())
     mem = lay.memory_bytes(value_bytes=4, index_bytes=4)
