@@ -40,6 +40,7 @@ ARCHS = {
     "rwkv6-7b": "rwkv6_7b",
     "jamba-1.5-large-398b": "jamba_1_5_large_398b",
     "musicgen-medium": "musicgen_medium",
+    "mellum2-12b-a2.5b": "mellum2_12b_a2_5b",
     "vgg19-cifar": "vgg19_cifar",
     "wrn40-4-cifar": "wrn40_4_cifar",
 }

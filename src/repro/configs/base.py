@@ -12,6 +12,7 @@ from repro.sparsity import SparsityConfig, SparsityPlan, lower_config
 
 __all__ = [
     "MoEConfig",
+    "YarnConfig",
     "MLAConfig",
     "MambaConfig",
     "RWKVConfig",
@@ -33,6 +34,18 @@ class MoEConfig:
     capacity_factor: float = 1.25
     aux_loss_coef: float = 0.01
     router_dtype: str = "float32"
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (transformers' ``rope_type: yarn``) on the layer
+    kinds it names; every other layer keeps plain RoPE at the same theta."""
+    factor: float
+    original_max_position: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float          # scales cos and sin
+    layer_kinds: tuple[str, ...]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +90,7 @@ class ModelConfig:
     hidden_act: str = "silu"         # 'gelu' -> GeGLU MLP
     rmsnorm_eps: float = 1e-6
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None   # None: plain RoPE everywhere
     max_seq_len: int = 8192
     tie_embeddings: bool = False
     logit_softcap: float = 0.0

@@ -523,6 +523,11 @@ def _pow2_divisors(x: int, cap: int) -> list[int]:
     return out
 
 
+def _lane_ok(tile: int, full: int) -> bool:
+    """A tile the TPU lowering takes as a block's last (lane) dimension."""
+    return tile % 128 == 0 or tile == full
+
+
 def _cap_steps(a: int, b: int, min_deg: int) -> int:
     """Max sparsity steps a (a, b)-sided factor can carry: 2-adic feasibility
     of the 2-lift construction + both degrees staying >= min_deg."""
@@ -564,7 +569,7 @@ def design_rbgp4(
     tm_target = 8 * group_rows * target_ui  # I-reuse saturates around here
 
     best = None
-    best_score = (-1, -1.0)
+    best_score = (-1, -1, -1.0)
     for G in _pow2_divisors(m, 64):
         for U_i in _pow2_divisors(m // G, 64):
             n_o_l = m // (G * U_i)
@@ -603,7 +608,11 @@ def design_rbgp4(
                     # mild preference for round (group_rows, chunk_cols)
                     pref = 1.0 - 0.01 * (abs(_v2(G) - _v2(group_rows))
                                          + abs(_v2(C) - _v2(chunk_cols)))
-                    score = (quality,
+                    # the kernels' activation and output tiles are (BN, TK)
+                    # and (BN, TM): TPU lanes take them only as whole
+                    # 128-lane multiples or the whole dimension
+                    lanes = int(_lane_ok(U_i * G, m) and _lane_ok(V_i * C, k))
+                    score = (lanes, quality,
                              u_rows * u_k * (0.5 + 0.5 * reuse) * pref)
                     if score > best_score:
                         best_score = score

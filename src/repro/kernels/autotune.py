@@ -259,7 +259,7 @@ def working_set_bytes(dims, bn: int, kind: str, el: int, w_el: int) -> int:
     """
     tile_out = bn * dims.tile_m                  # (BN, TM) output tile
     x_blk = 2 * bn * dims.tile_k * el            # gathered input tile
-    w_tile = dims.tile_m * dims.d_i * dims.chunk_cols
+    w_tile = dims.tile_m * dims.block_cols       # one values block
     if "sddmm" in kind:
         # f32 accumulator and dW tile (TM, d_i*C); cotangent (BN, TM) in
         return w_tile * (4 + 2 * el) + 2 * tile_out * el + x_blk

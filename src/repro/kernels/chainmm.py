@@ -140,6 +140,11 @@ class ChainDims:
         return self.d_head * self.inner
 
     @property
+    def block_cols(self) -> int:
+        """Lane width of a values block: one head slot."""
+        return self.inner
+
+    @property
     def full_col_starts(self) -> tuple[int, ...]:
         """col_starts of a row group whose blocks tile the X tile densely
         in order — the contiguous-slice fast path."""

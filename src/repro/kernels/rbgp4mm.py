@@ -145,6 +145,33 @@ class KernelDims:
     def data_cols(self) -> int:
         return self.d_o * self.d_i * self.chunk_cols
 
+    @property
+    def slot_cols(self) -> int:
+        """Compact columns of one outer slot (d_i * C)."""
+        return self.d_i * self.chunk_cols
+
+    @property
+    def slots_per_block(self) -> int:
+        """Outer slots per values block.
+
+        A values block's lane dim must be a multiple of 128 or the whole
+        row.  A slot of whole 128-lane tiles is its own block (1); a
+        narrower slot that divides 128 shares a 128-lane block with its
+        neighbours (the row's last block may run past the row's end); any
+        other takes the whole row.  The kernels pick the slot inside the
+        block by static lane slices."""
+        w = self.slot_cols
+        if w % 128 == 0:
+            return 1
+        if 128 % w == 0 and self.data_cols > 128:
+            return 128 // w
+        return self.d_o
+
+    @property
+    def block_cols(self) -> int:
+        """Lane width of a values block."""
+        return self.slots_per_block * self.slot_cols
+
     @classmethod
     def from_layout(cls, layout) -> "KernelDims":
         sp = layout.spec
@@ -216,6 +243,37 @@ def _resolve_block_n(block_n, dims: KernelDims, n: int, dtype, kind: str,
     return res.block_n, res.grid_order
 
 
+def _in_slot(dims: KernelDims, kk, body) -> None:
+    """``body(cols)`` for outer slot ``kk``, ``cols`` the static lane slice
+    that holds it inside its values block (one branch per position of a
+    slot in a block: see ``KernelDims.slots_per_block``), or None where a
+    slot is the whole block."""
+    r, w = dims.slots_per_block, dims.slot_cols
+    if r == 1:
+        body(None)
+        return
+    for t in range(r):
+        pl.when(kk % r == t)(functools.partial(body, slice(t * w, (t + 1) * w)))
+
+
+def _slot_values(ref, cols, lead: tuple = ()):
+    """The values of one slot in a values block (``_in_slot``'s ``cols``)."""
+    return ref[lead or ...] if cols is None else ref[(*lead, slice(None), cols)]
+
+
+def _values_spec(dims: KernelDims, row_and_slot, lead: tuple = ()):
+    """BlockSpec of the compact values (or their gradient): rows of tile
+    ``i``, the block holding slot ``kk``; ``row_and_slot(*grid, adj)``
+    gives ``(lead..., i, kk)``."""
+    r = dims.slots_per_block
+
+    def index(*args):
+        *head, i, kk = row_and_slot(*args)
+        return (*head, i, kk // r)
+
+    return pl.BlockSpec((*lead, dims.tile_m, dims.block_cols), index)
+
+
 # ---------------------------------------------------------------------------
 # Forward: O = W_s @ I
 # ---------------------------------------------------------------------------
@@ -228,23 +286,28 @@ def _mm_kernel(dims: KernelDims, adj_ref, w_ref, x_ref, o_ref, acc_ref):
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    G, C, d_i = dims.group_rows, dims.chunk_cols, dims.d_i
+    G, C = dims.group_rows, dims.chunk_cols
+
     # Unrolled loop over inner row-groups; all slicing is static (G_i is a
     # trace-time constant), so each iteration is a dense (G x d_i*C) @
     # (d_i*C x BN) matmul on the MXU.
-    for ui in range(dims.u_i):
-        w_u = w_ref[ui * G:(ui + 1) * G, :]  # (G, d_i*C)
-        cols = dims.adj_i[ui]
-        if len(cols) == dims.v_i:
-            # complete inner graph: contiguous slice, no concat needed
-            x_u = x_ref[...]
-        else:
-            x_u = jnp.concatenate(
-                [x_ref[vi * C:(vi + 1) * C, :] for vi in cols], axis=0
-            )  # (d_i*C, BN)
-        acc_ref[ui * G:(ui + 1) * G, :] += jnp.dot(
-            w_u, x_u, preferred_element_type=jnp.float32
-        )
+    def accumulate(slot):
+        for ui in range(dims.u_i):
+            w_u = w_ref[ui * G:(ui + 1) * G,
+                        slice(None) if slot is None else slot]  # (G, d_i*C)
+            cols = dims.adj_i[ui]
+            if len(cols) == dims.v_i:
+                # complete inner graph: contiguous slice, no concat needed
+                x_u = x_ref[...]
+            else:
+                x_u = jnp.concatenate(
+                    [x_ref[vi * C:(vi + 1) * C, :] for vi in cols], axis=0
+                )  # (d_i*C, BN)
+            acc_ref[ui * G:(ui + 1) * G, :] += jnp.dot(
+                w_u, x_u, preferred_element_type=jnp.float32
+            )
+
+    _in_slot(dims, kk, accumulate)
 
     @pl.when(kk == dims.d_o - 1)
     def _write():
@@ -287,7 +350,6 @@ def rbgp4mm(
         x = jnp.pad(x, ((0, 0), (0, n_pad - n)))
 
     grid = (dims.n_row_tiles, n_pad // bn, dims.d_o)
-    dcols = dims.d_i * dims.chunk_cols
 
     out = pl.pallas_call(
         functools.partial(_mm_kernel, dims),
@@ -295,7 +357,7 @@ def rbgp4mm(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((dims.tile_m, dcols), lambda i, j, kk, adj: (i, kk)),
+                _values_spec(dims, lambda i, j, kk, adj: (i, kk)),
                 pl.BlockSpec((dims.tile_k, bn), lambda i, j, kk, adj: (adj[i, kk], j)),
             ],
             out_specs=pl.BlockSpec(
@@ -306,7 +368,7 @@ def rbgp4mm(
         out_shape=jax.ShapeDtypeStruct((m, n_pad), out_dtype),
         compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(adj_o, w_data.reshape(m, dims.d_o * dcols), x)
+    )(adj_o, w_data, x)
     return out[:, :n] if n_pad != n else out
 
 
@@ -317,6 +379,7 @@ def rbgp4mm(
 def _sddmm_kernel(dims: KernelDims, adj_ref, do_ref, x_ref, dw_ref, acc_ref):
     """One (i, k, j) grid cell: dWtile(i, k) += dOtile(i, j) @ Itile^T."""
     jj = pl.program_id(2)
+    kk = _slot_id(dims, 1)
 
     @pl.when(jj == 0)
     def _zero():
@@ -337,7 +400,27 @@ def _sddmm_kernel(dims: KernelDims, adj_ref, do_ref, x_ref, dw_ref, acc_ref):
 
     @pl.when(jj == pl.num_programs(2) - 1)
     def _write():
-        dw_ref[...] = acc_ref[...].astype(dw_ref.dtype)
+        _write_slot(dims, kk, dw_ref, acc_ref)
+
+
+def _slot_id(dims: KernelDims, slot_axis: int):
+    """The grid's outer slot (axis ``slot_axis``) where a values block holds
+    more than one; read outside any ``pl.when``."""
+    return pl.program_id(slot_axis) if dims.slots_per_block > 1 else None
+
+
+def _write_slot(dims: KernelDims, kk, dw_ref, acc_ref,
+                lead: tuple = ()) -> None:
+    """Store slot ``kk``'s accumulated gradient into its values block
+    (``lead`` indexes the block's unit expert dim)."""
+    if dims.slots_per_block == 1:
+        dw_ref[lead or ...] = acc_ref[...].astype(dw_ref.dtype)
+        return
+
+    def write(cols):
+        dw_ref[(*lead, slice(None), cols)] = acc_ref[...].astype(dw_ref.dtype)
+
+    _in_slot(dims, kk, write)
 
 
 def rbgp4_sddmm(
@@ -376,7 +459,6 @@ def rbgp4_sddmm(
         x = jnp.pad(x, ((0, 0), (0, n_pad - n)))
 
     grid = (dims.n_row_tiles, dims.d_o, n_pad // bn)
-    dcols = dims.d_i * dims.chunk_cols
 
     out = pl.pallas_call(
         functools.partial(_sddmm_kernel, dims),
@@ -387,12 +469,11 @@ def rbgp4_sddmm(
                 pl.BlockSpec((dims.tile_m, bn), lambda i, kk, j, adj: (i, j)),
                 pl.BlockSpec((dims.tile_k, bn), lambda i, kk, j, adj: (adj[i, kk], j)),
             ],
-            out_specs=pl.BlockSpec(
-                (dims.tile_m, dcols), lambda i, kk, j, adj: (i, kk)
-            ),
-            scratch_shapes=[pltpu.VMEM((dims.tile_m, dcols), jnp.float32)],
+            out_specs=_values_spec(dims, lambda i, kk, j, adj: (i, kk)),
+            scratch_shapes=[pltpu.VMEM((dims.tile_m, dims.slot_cols),
+                                       jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((m, dims.d_o * dcols), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((m, dims.data_cols), out_dtype),
         compiler_params=_params("parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
     )(adj_o, d_out, x)
@@ -511,7 +592,8 @@ def _mm_rhs_kernel(dims: KernelDims, act: Optional[str], has_bias: bool,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    _rhs_accumulate(dims, x_ref[...], w_ref[...], acc_ref, scales=s_ref)
+    _in_slot(dims, kk, lambda cols: _rhs_accumulate(
+        dims, x_ref[...], _slot_values(w_ref, cols), acc_ref, scales=s_ref))
 
     @pl.when(kk == dims.d_o - 1)
     def _write():
@@ -582,7 +664,6 @@ def rbgp4mm_rhs(
             residual = jnp.pad(residual, ((0, n_pad - n), (0, 0)))
 
     n_tiles, m_tiles = n_pad // bn, dims.n_row_tiles
-    dcols = dims.d_i * dims.chunk_cols
 
     # ``i`` indexes token-tiles, ``j`` row-tiles in both orders; "mn" swaps
     # which one is the outer (slower-varying) grid dimension.
@@ -611,9 +692,9 @@ def rbgp4mm_rhs(
 
     in_specs = [
         pl.BlockSpec((bn, dims.tile_k), x_map),
-        pl.BlockSpec((dims.tile_m, dcols), w_map),
+        _values_spec(dims, w_map),
     ]
-    operands = [x, w_data.reshape(m, dims.d_o * dcols)]
+    operands = [x, w_data]
     if scales is not None:
         # one f32 scale per (G, C) leaf block; the (j, kk) tile owns the
         # (u_i, d_i) scale sub-block matching its value tile
@@ -690,6 +771,7 @@ def _sddmm_rhs_accumulate(dims: KernelDims, g, x, acc_ref) -> None:
 def _sddmm_rhs_kernel(dims: KernelDims, adj_ref, g_ref, x_ref, dw_ref, acc_ref):
     """One (i, k, j) grid cell of the token-major SDDMM."""
     jj = pl.program_id(2)
+    kk = _slot_id(dims, 1)
 
     @pl.when(jj == 0)
     def _zero():
@@ -699,7 +781,7 @@ def _sddmm_rhs_kernel(dims: KernelDims, adj_ref, g_ref, x_ref, dw_ref, acc_ref):
 
     @pl.when(jj == pl.num_programs(2) - 1)
     def _write():
-        dw_ref[...] = acc_ref[...].astype(dw_ref.dtype)
+        _write_slot(dims, kk, dw_ref, acc_ref)
 
 
 def rbgp4_sddmm_rhs(
@@ -736,7 +818,6 @@ def rbgp4_sddmm_rhs(
         x = jnp.pad(x, ((0, n_pad - n), (0, 0)))
 
     grid = (dims.n_row_tiles, dims.d_o, n_pad // bn)
-    dcols = dims.d_i * dims.chunk_cols
 
     out = pl.pallas_call(
         functools.partial(_sddmm_rhs_kernel, dims),
@@ -747,12 +828,11 @@ def rbgp4_sddmm_rhs(
                 pl.BlockSpec((bn, dims.tile_m), lambda i, kk, j, adj: (j, i)),
                 pl.BlockSpec((bn, dims.tile_k), lambda i, kk, j, adj: (j, adj[i, kk])),
             ],
-            out_specs=pl.BlockSpec(
-                (dims.tile_m, dcols), lambda i, kk, j, adj: (i, kk)
-            ),
-            scratch_shapes=[pltpu.VMEM((dims.tile_m, dcols), jnp.float32)],
+            out_specs=_values_spec(dims, lambda i, kk, j, adj: (i, kk)),
+            scratch_shapes=[pltpu.VMEM((dims.tile_m, dims.slot_cols),
+                                       jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((m, dims.d_o * dcols), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((m, dims.data_cols), out_dtype),
         compiler_params=_params("parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
     )(adj_o, g, x)
@@ -787,7 +867,9 @@ def _mm_rhs_stacked_kernel(dims: KernelDims, act: Optional[str],
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    _rhs_accumulate(dims, x_ref[0], w_ref[0], acc_ref, scales=s_ref)
+    _in_slot(dims, kk, lambda cols: _rhs_accumulate(
+        dims, x_ref[0], _slot_values(w_ref, cols, (0,)), acc_ref,
+        scales=s_ref))
 
     @pl.when(kk == dims.d_o - 1)
     def _write():
@@ -852,15 +934,13 @@ def rbgp4mm_rhs_stacked(
         x = jnp.pad(x, ((0, 0), (0, n_pad - n), (0, 0)))
 
     grid = (e, n_pad // bn, dims.n_row_tiles, dims.d_o)
-    dcols = dims.d_i * dims.chunk_cols
 
     in_specs = [
         pl.BlockSpec((1, bn, dims.tile_k),
                      lambda ee, i, j, kk, adj: (ee, i, adj[j, kk])),
-        pl.BlockSpec((1, dims.tile_m, dcols),
-                     lambda ee, i, j, kk, adj: (ee, j, kk)),
+        _values_spec(dims, lambda ee, i, j, kk, adj: (ee, j, kk), lead=(1,)),
     ]
-    operands = [x, w_data.reshape(e, m, dims.d_o * dcols)]
+    operands = [x, w_data]
     if scales is not None:
         in_specs.append(
             pl.BlockSpec((None, None, dims.u_i, dims.d_i),
@@ -913,6 +993,7 @@ def _sddmm_rhs_stacked_kernel(dims: KernelDims, adj_ref, g_ref, x_ref,
                               dw_ref, acc_ref):
     """One (e, i, k, j) grid cell of the stacked token-major SDDMM."""
     jj = pl.program_id(3)
+    kk = _slot_id(dims, 2)
 
     @pl.when(jj == 0)
     def _zero():
@@ -922,7 +1003,7 @@ def _sddmm_rhs_stacked_kernel(dims: KernelDims, adj_ref, g_ref, x_ref,
 
     @pl.when(jj == pl.num_programs(3) - 1)
     def _write():
-        dw_ref[0] = acc_ref[...].astype(dw_ref.dtype)
+        _write_slot(dims, kk, dw_ref, acc_ref, lead=(0,))
 
 
 def rbgp4_sddmm_rhs_stacked(
@@ -958,7 +1039,6 @@ def rbgp4_sddmm_rhs_stacked(
         x = jnp.pad(x, ((0, 0), (0, n_pad - n), (0, 0)))
 
     grid = (e, dims.n_row_tiles, dims.d_o, n_pad // bn)
-    dcols = dims.d_i * dims.chunk_cols
 
     out = pl.pallas_call(
         functools.partial(_sddmm_rhs_stacked_kernel, dims),
@@ -971,12 +1051,12 @@ def rbgp4_sddmm_rhs_stacked(
                 pl.BlockSpec((1, bn, dims.tile_k),
                              lambda ee, i, kk, j, adj: (ee, j, adj[i, kk])),
             ],
-            out_specs=pl.BlockSpec(
-                (1, dims.tile_m, dcols), lambda ee, i, kk, j, adj: (ee, i, kk)
-            ),
-            scratch_shapes=[pltpu.VMEM((dims.tile_m, dcols), jnp.float32)],
+            out_specs=_values_spec(
+                dims, lambda ee, i, kk, j, adj: (ee, i, kk), lead=(1,)),
+            scratch_shapes=[pltpu.VMEM((dims.tile_m, dims.slot_cols),
+                                       jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((e, m, dims.d_o * dcols), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((e, m, dims.data_cols), out_dtype),
         compiler_params=_params("parallel", "parallel", "arbitrary",
                                 "arbitrary"),
         interpret=interpret,

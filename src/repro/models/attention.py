@@ -29,7 +29,7 @@ import numpy as np
 from repro.configs.base import MLAConfig, ModelConfig
 from repro.parallel.constrain import shard
 from repro.sparsity import SparseLinear, SparsityConfig
-from .common import apply_rope, rope_frequencies
+from .common import apply_rope, layer_rope, rope_frequencies
 
 __all__ = [
     "GQAttention", "MLAttention", "init_cache_gqa", "init_cache_mla",
@@ -191,7 +191,8 @@ class GQAttention:
         self.wk = SparseLinear(d, cfg.n_kv_heads * hd, sp, name=f"{name}.wk")
         self.wv = SparseLinear(d, cfg.n_kv_heads * hd, sp, name=f"{name}.wv")
         self.wo = SparseLinear(cfg.n_heads * hd, d, sp, name=f"{name}.wo")
-        self.inv_freq = rope_frequencies(hd, cfg.rope_theta)
+        self.inv_freq, self.rope_scale = layer_rope(
+            cfg, "swa" if window else "attn")
 
     def init(self, key) -> dict:
         ks = jax.random.split(key, 4)
@@ -215,8 +216,8 @@ class GQAttention:
         q = self.wq.apply(params["wq"], x).reshape(B, S, H, hd)
         k = self.wk.apply(params["wk"], x).reshape(B, S, Hkv, hd)
         v = self.wv.apply(params["wv"], x).reshape(B, S, Hkv, hd)
-        q = apply_rope(q, self.inv_freq, positions)
-        k = apply_rope(k, self.inv_freq, positions)
+        q = apply_rope(q, self.inv_freq, positions, self.rope_scale)
+        k = apply_rope(k, self.inv_freq, positions, self.rope_scale)
 
         if block_tables is not None:
             if S != 1:

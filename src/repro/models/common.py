@@ -1,6 +1,7 @@
 """Shared model components: norms, rotary embeddings, embedding tables."""
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -11,6 +12,8 @@ __all__ = [
     "RMSNorm",
     "Embedding",
     "rope_frequencies",
+    "yarn_frequencies",
+    "layer_rope",
     "apply_rope",
     "make_causal_mask",
     "make_window_mask",
@@ -63,11 +66,48 @@ def rope_frequencies(head_dim: int, theta: float = 10000.0) -> jax.Array:
     return jnp.asarray(inv, jnp.float32)
 
 
-def apply_rope(x: jax.Array, inv_freq: jax.Array, positions: jax.Array) -> jax.Array:
-    """x: (B, S, H, hd); positions: (B, S) absolute positions."""
+def yarn_frequencies(head_dim: int, theta: float, yarn) -> jax.Array:
+    """(head_dim//2,) YaRN inverse frequencies (transformers'
+    ``_compute_yarn_parameters``): dimensions that turn fewer than
+    ``beta_slow`` times over the original context are interpolated
+    (divided by ``factor``), those that turn more than ``beta_fast`` times
+    keep their frequency, and a linear ramp blends the ones between."""
+    d = head_dim
+
+    def dim_of(rotations):
+        return (d * math.log(yarn.original_max_position
+                             / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d // 2) - low) / (high - low), 0.0, 1.0)
+    extra = 1.0 / (theta ** (np.arange(0, d, 2) / d))
+    inv = extra / yarn.factor * ramp + extra * (1.0 - ramp)
+    return jnp.asarray(inv, jnp.float32)
+
+
+def layer_rope(cfg, kind: str) -> tuple[jax.Array, float]:
+    """Rotary inverse frequencies and cos/sin scale of a ``kind`` layer:
+    YaRN where ``cfg.rope_scaling`` names the kind, else plain RoPE."""
+    yarn = cfg.rope_scaling
+    if yarn is not None and kind in yarn.layer_kinds:
+        return (yarn_frequencies(cfg.head_dim_, cfg.rope_theta, yarn),
+                yarn.attention_factor)
+    return rope_frequencies(cfg.head_dim_, cfg.rope_theta), 1.0
+
+
+def apply_rope(x: jax.Array, inv_freq: jax.Array, positions: jax.Array,
+               scale: float = 1.0) -> jax.Array:
+    """x: (B, S, H, hd); positions: (B, S) absolute positions.  ``scale``
+    multiplies cos and sin (YaRN's attention factor)."""
     ang = positions[:, :, None, None].astype(jnp.float32) * inv_freq
     c = jnp.cos(ang)  # (B, S, 1, hd/2)
     s = jnp.sin(ang)
+    if scale != 1.0:
+        c, s = c * scale, s * scale
     x1, x2 = jnp.split(x, 2, axis=-1)
     y1 = x1 * c - x2 * s
     y2 = x2 * c + x1 * s

@@ -160,6 +160,14 @@ class LMModel:
         return ce + aux.astype(jnp.float32), (ce, aux)
 
     # -- serving ------------------------------------------------------------------
+    def serve_moe_rows(self, tokens: int) -> tuple[int, int]:
+        """(expert-buffer rows, routed (token, expert) pairs) that one
+        serving call (prefill, chunk or decode step) over ``tokens`` token
+        rows computes, summed over the MoE layers; from static shapes.
+        Serving calls carry a cache, which ``DecoderLayer.apply`` routes
+        at full capacity."""
+        return self.stack.moe_rows(tokens, full_capacity=True)
+
     def init_cache(self, batch: int, cache_len: int, dtype=jnp.bfloat16,
                    *, full_length: bool = False):
         return self.stack.init_cache(batch, cache_len, dtype,

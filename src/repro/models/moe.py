@@ -271,6 +271,24 @@ class MoELayer:
             p["shared"] = self.shared.init(ks[2])
         return p
 
+    def capacity(self, tokens: int, full_capacity: bool) -> int:
+        """Rows C of each expert's buffer for a routing group of
+        ``tokens`` tokens: all of them at full capacity, else the top-k
+        share scaled by ``capacity_factor``."""
+        if full_capacity:
+            return tokens
+        moe = self.moe
+        return max(int(math.ceil(tokens * moe.top_k / moe.n_experts
+                                 * moe.capacity_factor)), 1)
+
+    def dispatch_rows(self, tokens: int,
+                      full_capacity: bool) -> tuple[int, int]:
+        """(expert-buffer rows E·C, routed (token, expert) pairs T·k) of
+        one call over ``tokens`` tokens in one routing group: the work the
+        experts compute against the work routing asked for."""
+        return (self.moe.n_experts * self.capacity(tokens, full_capacity),
+                tokens * self.moe.top_k)
+
     def _n_groups(self, batch_dim: int) -> int:
         mesh = current_mesh()
         if mesh is None:
@@ -329,10 +347,7 @@ class MoELayer:
         ndp = int(np.prod([mesh.shape[a] for a in dp]))
         nmp = mesh.shape["model"]
         TL = T // ndp
-        if full_capacity:
-            C = TL
-        else:
-            C = max(int(math.ceil(TL * K / E * moe.capacity_factor)), 1)
+        C = self.capacity(TL, full_capacity)
         epm = -(-E // nmp)          # experts per model rank
         Ep = epm * nmp              # padded expert count
 
@@ -414,56 +429,63 @@ class MoELayer:
         G = self._n_groups(B)
         TL = T // G  # tokens per routing group
         xg = shard(x.reshape(G, TL, D), "dp", None, None)
+        # named scopes mark the four phases in the ops' HLO ``op_name``
+        # metadata (the stacked kernels inside ``moe.experts`` keep their
+        # ``rbgp4.*`` names)
+        with jax.named_scope("moe.route"):
+            # router in f32 (tiny, replicated)
+            logits = jnp.einsum(
+                "gtd,ed->gte", xg.astype(jnp.float32),
+                params["router"].astype(jnp.float32),
+            )
+            probs = jax.nn.softmax(logits, axis=-1)  # (G, TL, E)
+            gates, idx = jax.lax.top_k(probs, K)  # (G, TL, K)
+            gates = gates / jnp.clip(gates.sum(-1, keepdims=True), 1e-9)
 
-        # router in f32 (tiny, replicated)
-        logits = jnp.einsum(
-            "gtd,ed->gte", xg.astype(jnp.float32),
-            params["router"].astype(jnp.float32),
-        )
-        probs = jax.nn.softmax(logits, axis=-1)  # (G, TL, E)
-        gates, idx = jax.lax.top_k(probs, K)  # (G, TL, K)
-        gates = gates / jnp.clip(gates.sum(-1, keepdims=True), 1e-9)
+            # per-group capacity + position-in-expert (cumsum over local
+            # slots)
+            C = self.capacity(TL, full_capacity)
+            e_flat = idx.reshape(G, TL * K)
+            onehot = jax.nn.one_hot(e_flat, E, dtype=jnp.int32)  # (G, TL*K, E)
+            pos = jnp.cumsum(onehot, axis=1) - 1
+            pos_in_e = jnp.take_along_axis(pos, e_flat[..., None],
+                                           axis=2)[..., 0]
+            keep = pos_in_e < C
 
-        # per-group capacity + position-in-expert (cumsum over local slots)
-        if full_capacity:
-            C = TL
-        else:
-            C = max(int(math.ceil(TL * K / E * moe.capacity_factor)), 1)
-        e_flat = idx.reshape(G, TL * K)
-        onehot = jax.nn.one_hot(e_flat, E, dtype=jnp.int32)  # (G, TL*K, E)
-        pos = jnp.cumsum(onehot, axis=1) - 1
-        pos_in_e = jnp.take_along_axis(pos, e_flat[..., None], axis=2)[..., 0]
-        keep = pos_in_e < C
+            # load-balance aux loss (Switch-style), averaged over groups
+            frac_tokens = jnp.mean(
+                jax.nn.one_hot(idx[..., 0], E, dtype=jnp.float32), axis=(0, 1)
+            )
+            frac_probs = jnp.mean(probs, axis=(0, 1))
+            aux = E * jnp.sum(frac_tokens * frac_probs) * moe.aux_loss_coef
 
-        # scatter tokens into (G, E, C, D): indices local to each group
-        gidx = jnp.broadcast_to(jnp.arange(G)[:, None], (G, TL * K))
-        tok = jnp.broadcast_to(
-            jnp.repeat(jnp.arange(TL), K)[None], (G, TL * K)
-        )
-        safe_e = jnp.where(keep, e_flat, 0)
-        safe_p = jnp.where(keep, pos_in_e, 0)
-        contrib = jnp.where(
-            keep[..., None], jnp.take_along_axis(xg, tok[..., None], axis=1), 0
-        ).astype(x.dtype)
-        buf = jnp.zeros((G, E, C, D), x.dtype).at[gidx, safe_e, safe_p].add(contrib)
-        buf = shard(buf, "dp", "tp", None, None)  # DP x EP
+        with jax.named_scope("moe.dispatch"):
+            # scatter tokens into (G, E, C, D): indices local to each group
+            gidx = jnp.broadcast_to(jnp.arange(G)[:, None], (G, TL * K))
+            tok = jnp.broadcast_to(
+                jnp.repeat(jnp.arange(TL), K)[None], (G, TL * K)
+            )
+            safe_e = jnp.where(keep, e_flat, 0)
+            safe_p = jnp.where(keep, pos_in_e, 0)
+            contrib = jnp.where(
+                keep[..., None],
+                jnp.take_along_axis(xg, tok[..., None], axis=1), 0
+            ).astype(x.dtype)
+            buf = jnp.zeros((G, E, C, D), x.dtype).at[
+                gidx, safe_e, safe_p].add(contrib)
+            buf = shard(buf, "dp", "tp", None, None)  # DP x EP
 
-        out_buf = self.experts.apply(params["experts"], buf)  # (G, E, C, D)
-        out_buf = shard(out_buf, "dp", "tp", None, None)
+        with jax.named_scope("moe.experts"):
+            out_buf = self.experts.apply(params["experts"], buf)  # (G, E, C, D)
+            out_buf = shard(out_buf, "dp", "tp", None, None)
 
-        # gather back, weighted by gates
-        got = out_buf[gidx, safe_e, safe_p]  # (G, TL*K, D)
-        got = jnp.where(keep[..., None], got, 0)
-        y = (got.reshape(G, TL, K, D)
-             * gates[..., None].astype(x.dtype)).sum(axis=2)
-        y = shard(y, "dp", None, None).reshape(B, S, D)
-
-        # load-balance aux loss (Switch-style), averaged over groups
-        frac_tokens = jnp.mean(
-            jax.nn.one_hot(idx[..., 0], E, dtype=jnp.float32), axis=(0, 1)
-        )
-        frac_probs = jnp.mean(probs, axis=(0, 1))
-        aux = E * jnp.sum(frac_tokens * frac_probs) * moe.aux_loss_coef
+        with jax.named_scope("moe.combine"):
+            # gather back, weighted by gates
+            got = out_buf[gidx, safe_e, safe_p]  # (G, TL*K, D)
+            got = jnp.where(keep[..., None], got, 0)
+            y = (got.reshape(G, TL, K, D)
+                 * gates[..., None].astype(x.dtype)).sum(axis=2)
+            y = shard(y, "dp", None, None).reshape(B, S, D)
 
         if self.shared is not None:
             y = y + self.shared.apply(params["shared"], x)

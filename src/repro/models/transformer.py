@@ -258,6 +258,15 @@ class Stack:
             params["scan"] = {}
         return params
 
+    def moe_rows(self, tokens: int, full_capacity: bool) -> tuple[int, int]:
+        """(expert-buffer rows, routed pairs) of one call over ``tokens``
+        tokens, summed over the MoE layers (``MoELayer.dispatch_rows``)."""
+        layers = (self.head_layers + self.period_layers * self.n_full
+                  + self.tail_layers)
+        rows = [m.ffn.dispatch_rows(tokens, full_capacity)
+                for m in layers if m.is_moe]
+        return sum(r for r, _ in rows), sum(k for _, k in rows)
+
     # -- caches ------------------------------------------------------------------
     def init_cache(self, batch: int, cache_len: int, dtype=jnp.bfloat16,
                    *, full_length: bool = False):

@@ -26,6 +26,7 @@ Two host-side pieces:
 """
 from __future__ import annotations
 
+import functools
 import os
 from typing import Iterable, Optional
 
@@ -52,6 +53,20 @@ def blocks_for_tokens(n_tokens: int, page_size: int) -> int:
     the 'worst-case reservation ⇒ lazy allocation never fails' argument.
     """
     return -(-n_tokens // page_size)
+
+
+@jax.jit
+def _set_rows(pool, idx, rows):
+    """``pool`` (n_blocks, page, ...) with block rows ``idx`` set to
+    ``rows``: one program per shape, where the eager ``.at[].set`` compiles
+    several small ones for every block count."""
+    return pool.at[idx].set(rows.astype(pool.dtype))
+
+
+@jax.jit
+def _set_rows_scanned(pool, idx, rows):
+    """:func:`_set_rows` of a scanned pool (T, n_blocks, page, ...)."""
+    return pool.at[:, idx].set(rows.astype(pool.dtype))
 
 
 class PageAllocator:
@@ -274,6 +289,7 @@ class PageAllocator:
             raise AssertionError(f"allocated blocks with refcount < 1: {bad}")
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2))
 def pack_prefill_pages(cache, n_blocks: int, page_size: int):
     """Reshape a batch-1 contiguous prefill cache into per-request pages.
 
@@ -389,8 +405,7 @@ class PagedKVCache:
         idx = jnp.asarray(blocks, jnp.int32)
 
         def scatter(pool, leaf, scan: bool):
-            leaf = leaf.astype(pool.dtype)
-            return pool.at[:, idx].set(leaf) if scan else pool.at[idx].set(leaf)
+            return (_set_rows_scanned if scan else _set_rows)(pool, idx, leaf)
 
         tm = jax.tree_util.tree_map
         self.pools = {
@@ -451,12 +466,18 @@ class PagedKVCache:
         """
         if not blocks:
             return
-        idx = jnp.asarray(blocks, jnp.int32)
+        # padded to a power of two by repeating the first block (marking a
+        # block twice is marking it once), so that releases of any size
+        # share a few programs
+        n = 1 << (len(blocks) - 1).bit_length()
+        idx = jnp.asarray(list(blocks) + [blocks[0]] * (n - len(blocks)),
+                          jnp.int32)
+        empty = jnp.int32(-1)
 
         def reset(leaf, scan: bool):
             if not jnp.issubdtype(leaf.dtype, jnp.integer):
                 return leaf
-            return leaf.at[:, idx].set(-1) if scan else leaf.at[idx].set(-1)
+            return (_set_rows_scanned if scan else _set_rows)(leaf, idx, empty)
 
         tm = jax.tree_util.tree_map
         self.pools = {

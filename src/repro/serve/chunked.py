@@ -24,6 +24,7 @@ budget by construction.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -47,6 +48,7 @@ def chunk_cache_len(max_request_len: int, page_size: int, chunk: int) -> int:
     return max(blocks * page_size, -(-max_request_len // chunk) * chunk)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2))
 def slice_cache(cache: Any, start: int, end: int) -> Any:
     """Slice a contiguous prefill cache to slots ``[start, end)``.
 
@@ -128,7 +130,8 @@ class ChunkedPrefillState:
     """
 
     req: Any                       # serve.engine.Request
-    cache: Any                     # persistent contiguous temp cache
+    cache: Any                     # persistent contiguous temp cache (None
+                                   # until the engine starts the first chunk)
     chunk: int
     tokens: Optional[np.ndarray] = None   # default: req.prompt
     pos: int = 0                   # tokens already fed

@@ -162,6 +162,8 @@ class ServingEngine:
             "finished": 0,
             "preemptions": 0, "fault_kills": 0, "resumed_prefills": 0,
             "fault_events": 0, "fault_paused_steps": 0,
+            # MoE work dispatched, from static shapes (``_count_moe``)
+            "moe_rows": 0, "moe_routed": 0,
         })
 
     # -- API -----------------------------------------------------------------------
@@ -250,6 +252,15 @@ class ServingEngine:
         return np.asarray(req.generated[-1], np.int32).reshape(
             (1,) + req.prompt.shape[1:]
         )
+
+    def _count_moe(self, tokens: int) -> None:
+        """Count one program call over ``tokens`` token rows: the expert-
+        buffer rows the model's MoE layers compute (``moe_rows``) and the
+        routed (token, expert) pairs (``moe_routed``), as the model reports
+        them (``serve_moe_rows``).  Shapes only, no sync."""
+        rows, routed = self.model.serve_moe_rows(tokens)
+        self.stats["moe_rows"] += rows
+        self.stats["moe_routed"] += routed
 
     def _span(self, name: str):
         """A ``repro.obs`` span of this engine, counted in ``stats``."""
@@ -632,8 +643,8 @@ class ContinuousEngine(ServingEngine):
 
         Spans (``repro.obs.span``): ``serve.step`` around the tick and,
         inside it, the phases ``serve.admit``, ``serve.prefill_full`` (single-
-        shot prefill), ``serve.prefill_chunk``, ``serve.pages`` (the eager
-        page moves), ``serve.decode``, ``serve.fetch``, ``serve.sample`` and
+        shot prefill), ``serve.prefill_chunk``, ``serve.pages`` (the page
+        moves), ``serve.decode``, ``serve.fetch``, ``serve.sample`` and
         ``serve.finish``.  Phases never nest, so their ``<phase>_s``
         counters sum to ``step_s``; page resets of a request preempted or
         expired during admission or decode growth count in that phase.
@@ -905,6 +916,7 @@ class ContinuousEngine(ServingEngine):
                     {"tokens": jnp.asarray(req.prefill_tokens[None])},
                     cache
                 )
+            self._count_moe(fed)
         if req.generated:
             self.stats["resumed_prefills"] += 1
         self.stats["prompt_tokens"] += fed
@@ -912,21 +924,35 @@ class ContinuousEngine(ServingEngine):
 
     # -- chunked prefill ---------------------------------------------------------------
     def _begin_chunked(self, req: Request) -> None:
-        """Allocate the request's prefill blocks and its temp prefill cache.
+        """Allocate the request's prefill blocks and queue its chunked
+        prefill.  Its temp prefill cache is made when its first chunk runs
+        (``_start_chunked``): chunks run one request at a time, oldest
+        first, so at most one temp cache is live however many admitted
+        requests wait for their first chunk."""
+        nb = self.kv.blocks_for(req.prefill_len)
+        got = self._ensure_blocks(req, nb - req.n_shared)
+        if got is None:
+            return   # req itself was preempted under pool pressure
+        req.blocks = req.blocks + got
+        self._prefilling[req.rid] = ChunkedPrefillState(
+            req=req, cache=None, chunk=self.prefill_chunk,
+            tokens=req.prefill_tokens,
+        )
+        if req.generated:
+            self.stats["resumed_prefills"] += 1
+
+    def _start_chunked(self, state: ChunkedPrefillState) -> None:
+        """Make the temp prefill cache of a request about to feed its first
+        chunk, filled from its claimed prefix pages if it has any.
 
         The temp cache has the ONE shared ``chunk_cache`` length for every
         request, so all prompts reuse a single compiled chunk program.
         Resumed requests chunk prompt ++ generated prefix (never longer
         than ``max_request_len``, so the shared cache always fits).
         """
-        nb = self.kv.blocks_for(req.prefill_len)
-        got = self._ensure_blocks(req, nb - req.n_shared)
-        if got is None:
-            return   # req itself was preempted under pool pressure
-        req.blocks = req.blocks + got
+        req = state.req
         cache = self.model.init_cache(1, self.chunk_cache, self.cache_dtype,
                                       full_length=True)
-        pos0 = 0
         if req.n_shared > 0 or req.cow_src is not None:
             cache, start, span = self._gather_prefix(req, cache)
             # chunk starts must stay multiples of ``prefill_chunk`` (the
@@ -934,14 +960,9 @@ class ContinuousEngine(ServingEngine):
             # the resume point down: the re-fed rows recompute over the
             # gathered prefix and land bit-identical, and only the
             # private page span is scattered at the end anyway
-            pos0 = start - start % self.prefill_chunk
-            cache = mask_cache_rows(cache, pos0, span)
-        self._prefilling[req.rid] = ChunkedPrefillState(
-            req=req, cache=cache, chunk=self.prefill_chunk,
-            tokens=req.prefill_tokens, pos=pos0,
-        )
-        if req.generated:
-            self.stats["resumed_prefills"] += 1
+            state.pos = start - start % self.prefill_chunk
+            cache = mask_cache_rows(cache, state.pos, span)
+        state.cache = cache
 
     def _run_prefill_chunk(self, finished: list[Request]) -> int:
         """Feed at most ONE chunk (of the oldest in-flight prefill) per
@@ -952,7 +973,10 @@ class ContinuousEngine(ServingEngine):
         rid = next(iter(self._prefilling))   # dict preserves FCFS order
         state = self._prefilling[rid]
         with self._span("serve.prefill_chunk"):
+            if state.cache is None:
+                self._start_chunked(state)
             fed = run_one_chunk(state, self.prefill_params, self._chunk)
+            self._count_moe(state.chunk)
             self.stats["prefill_chunks"] += 1
             self.stats["prompt_tokens"] += fed
         if state.done:
@@ -1039,6 +1063,7 @@ class ContinuousEngine(ServingEngine):
             self.params, jnp.asarray(tokens), self.kv.pools,
             jnp.asarray(bt), jnp.asarray(positions),
         )
+        self._count_moe(B)
         self.stats["decode_steps"] += 1
         self.stats["decode_row_steps"] += len(active)
         return logits, active

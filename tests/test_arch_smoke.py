@@ -82,8 +82,8 @@ def test_reduced_decode_step(arch):
 
 
 def test_all_archs_present():
-    assert len(LM_ARCHS) == 10
-    assert len(list_archs()) == 12  # + the paper's two vision models
+    assert len(LM_ARCHS) == 11
+    assert len(list_archs()) == 13  # + the paper's two vision models
 
 
 @pytest.mark.parametrize("arch", LM_ARCHS)
@@ -128,6 +128,7 @@ def test_full_config_param_counts():
         "rwkv6-7b": (7.0e9, 8.5e9),
         "jamba-1.5-large-398b": (3.5e11, 4.4e11),
         "musicgen-medium": (1.3e9, 2.0e9),
+        "mellum2-12b-a2.5b": (1.15e10, 1.3e10),
     }
     for arch, (lo, hi) in expect.items():
         cfg = get_config(arch)

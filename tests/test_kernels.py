@@ -271,3 +271,88 @@ def test_rhs_linear_grads_match_lhs():
     for a, b in zip(gr, gl):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-5)
+
+
+# -- slots narrower than 128 lanes (widths with odd factors) ----------------------
+
+# A slot (d_i * C values of one outer tile) 64 or 32 lanes wide, an odd
+# number of slots per row: a 128-lane values block holds two or four slots
+# and the row's last block runs past its end.
+NARROW = {
+    "slot64": RBGP4Spec(g_o=(2, 3), g_r=(2, 4), g_i=(8, 8), g_b=(8, 8),
+                        sp_o=0.0, sp_i=0.75, seed=3),
+    "slot32": RBGP4Spec(g_o=(2, 5), g_r=(2, 2), g_i=(8, 8), g_b=(8, 8),
+                        sp_o=0.0, sp_i=0.75, seed=4),
+    "slot64_outer": RBGP4Spec(g_o=(2, 6), g_r=(2, 4), g_i=(8, 8),
+                              g_b=(8, 8), sp_o=0.5, sp_i=0.75, seed=5),
+}
+
+
+@pytest.mark.parametrize("form", ["linear", "stacked", "matmul"])
+@pytest.mark.parametrize("name", sorted(NARROW))
+def test_narrow_slot_kernels_match_the_dense_oracle(name, form):
+    """Forward, SDDMM and dx kernels (dx on the transposed layout, whose
+    slots are narrow too) against the ``ref`` backend's dense unpack."""
+    lay = RBGP4Layout(NARROW[name])
+    op = RBGP4Op(lay, interpret=True, block_n=16)
+    assert op.dims.slots_per_block > 1 and op.dims.data_cols % 128 != 0
+    assert op.dims.slot_cols < 128 and op.dims_t.slot_cols < 128
+    kw, kx = jax.random.split(jax.random.PRNGKey(len(name)))
+    e = 3 if form == "stacked" else None
+    lead = (e,) if e else ()
+    w = rand(kw, (*lead, *lay.data_shape), jnp.float32)
+    if form == "matmul":
+        x = rand(kx, (lay.k, 24), jnp.float32)
+        prog = lambda w, x: op.matmul(w, x)
+        want = lambda w, x: ref.unpack_dense(lay, w) @ x
+    elif form == "linear":
+        x = rand(kx, (24, lay.k), jnp.float32)
+        prog = lambda w, x: op.linear(x, w, fuse="silu")
+        want = lambda w, x: jax.nn.silu(x @ ref.unpack_dense(lay, w).T)
+    else:
+        x = rand(kx, (e, 24, lay.k), jnp.float32)
+        prog = lambda w, x: op.linear_stacked(x, w)
+        want = lambda w, x: jnp.einsum(
+            "enk,emk->enm", x, jax.vmap(lambda v: ref.unpack_dense(lay, v))(w))
+    np.testing.assert_allclose(np.asarray(prog(w, x)), np.asarray(want(w, x)),
+                               rtol=1e-4, atol=1e-4)
+    loss = lambda f: lambda w, x: jnp.sum(jnp.sin(f(w, x)))
+    gk = jax.grad(loss(prog), argnums=(0, 1))(w, x)
+    gr = jax.grad(loss(want), argnums=(0, 1))(w, x)
+    for a, b in zip(gk, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
+# The five Mistral-NeMo-12B projections (out x in): their specs, and so
+# their kernel blocks, are those every earlier chip run measured.
+NEMO_SPECS = {
+    (4096, 5120): RBGP4Spec((4, 40), (2, 16), (64, 1), (8, 8), 0.75, 0.0),
+    (1024, 5120): RBGP4Spec((1, 5), (2, 16), (64, 8), (8, 8), 0.0, 0.75),
+    (5120, 4096): RBGP4Spec((5, 4), (2, 16), (64, 8), (8, 8), 0.0, 0.75),
+    (14336, 5120): RBGP4Spec((14, 10), (2, 16), (64, 4), (8, 8), 0.5, 0.5),
+    (5120, 14336): RBGP4Spec((5, 14), (2, 16), (64, 8), (8, 8), 0.0, 0.75),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(NEMO_SPECS))
+def test_nemo_designs_are_pinned(shape):
+    spec = design_rbgp4(*shape, 0.75)
+    assert spec == NEMO_SPECS[shape]
+    for sp in (spec, spec.transpose()):
+        dims = KernelDims.from_layout(RBGP4Layout(sp))
+        assert dims.slots_per_block == 1
+        assert dims.block_cols == dims.d_i * dims.chunk_cols
+
+
+@pytest.mark.parametrize("shape", [(896, 2304), (2304, 896), (4096, 2304),
+                                   (512, 2304), (2304, 4096), (4096, 11008)])
+def test_odd_widths_design_lane_tiles_at_exact_sparsity(shape):
+    """Mellum's and DeepSeek-7B's widths (896 = 7 * 128, 2304 = 9 * 256,
+    11008 = 43 * 256): the activation and output tiles are whole 128-lane
+    multiples or the whole dimension, in both directions, at exactly 0.75."""
+    spec = design_rbgp4(*shape, 0.75)
+    assert spec.sparsity == 0.75
+    for sp in (spec, spec.transpose()):
+        for tile, full in ((sp.tile_m, sp.m), (sp.tile_k, sp.k)):
+            assert tile % 128 == 0 or tile == full

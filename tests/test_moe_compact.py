@@ -157,3 +157,50 @@ def test_stacked_experts_unsupported_pattern_names_offender():
     msg = str(ei.value)
     assert "'block'" in msg            # the pattern that was passed
     assert "rbgp4" in msg and "dense" in msg   # what is supported
+
+
+def test_moe_phases_carry_named_scopes():
+    """Routing, dispatch, the experts and the combine each carry their
+    scope in the ops' ``op_name`` metadata; the stacked kernels inside
+    ``moe.experts`` keep their ``rbgp4.fwd`` name."""
+    import re
+
+    layer = MoELayer(128, MoEConfig(n_experts=8, top_k=2, d_expert=128),
+                     SP_COMPACT, act="silu")
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((2, 8, 128), jnp.bfloat16)
+    text = jax.jit(lambda p, x: layer.apply(p, x, full_capacity=True)).lower(
+        params, x).as_text(debug_info=True)
+    for scope in ("moe.route", "moe.dispatch", "moe.experts", "moe.combine"):
+        assert re.search(rf'["/(]{re.escape(scope)}[/)"]', text), scope
+    assert re.search(r"moe\.experts/[^\"]*rbgp4\.fwd", text)
+
+
+@pytest.mark.parametrize("full_capacity", [True, False])
+def test_dispatch_rows_are_the_buffer_the_layer_builds(full_capacity):
+    """``dispatch_rows`` reports the rows of the (E, C, D) expert buffer
+    the layer's program really builds, and T·k routed pairs."""
+    moe = MoEConfig(n_experts=8, top_k=2, d_expert=128, capacity_factor=1.25)
+    layer = MoELayer(128, moe, SP_COMPACT, act="silu")
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((2, 24, 128), jnp.bfloat16)
+    text = jax.jit(lambda p, x: layer.apply(
+        p, x, full_capacity=full_capacity)).lower(params, x).as_text()
+    rows, routed = layer.dispatch_rows(48, full_capacity)
+    assert routed == 48 * 2
+    assert rows == (8 * 48 if full_capacity else 8 * 15)
+    assert f"tensor<1x8x{rows // 8}x128xbf16>" in text
+
+
+def test_serve_moe_rows_sums_the_moe_layers_only():
+    """The model's count covers its MoE layers (not the leading dense
+    one) at full capacity, as its serving programs route."""
+    from repro.configs import get_config, reduce_config
+    from repro.models import LMModel
+
+    cfg = reduce_config(get_config("deepseek-v2-236b"))
+    model = LMModel(cfg)
+    n_moe = sum(cfg.is_moe_layer(i) for i in range(cfg.n_layers))
+    assert 0 < n_moe < cfg.n_layers
+    E, k = cfg.moe.n_experts, cfg.moe.top_k
+    assert model.serve_moe_rows(5) == (n_moe * E * 5, n_moe * k * 5)

@@ -412,3 +412,40 @@ def test_block_table_none_vs_empty_rows():
     assert list(bt[2]) == [-1, -1, -1, -1]
     with pytest.raises(ValueError, match="active but holds no blocks"):
         PagedKVCache.block_table(None, [[2], []], 2)
+
+
+def test_reset_blocks_marks_exactly_the_released_blocks_in_few_programs():
+    """Releasing n blocks marks those blocks' positions -1 in every layer
+    and leaves every other block as it was; releases of 1..40 blocks share
+    one program per power of two (the index list is padded by repeating
+    its first block), not a set of programs per count."""
+    import jax
+    import numpy as np
+
+    from repro.configs import get_config, reduce_config
+    from repro.models.lm import LMModel
+    from repro.serve import cache as cache_mod
+    from repro.serve.cache import PagedKVCache
+
+    model = LMModel(reduce_config(get_config("tinyllama-1.1b")))
+    kv = PagedKVCache(model, 48, 4)
+    kv.pools = jax.tree_util.tree_map(
+        lambda p: p if p.dtype.kind == "f" else p.at[...].set(7), kv.pools)
+    fns = (cache_mod._set_rows, cache_mod._set_rows_scanned)
+    before = [f._cache_size() for f in fns]
+    released = set()
+    rng = np.random.default_rng(0)
+    for n in range(1, 41):
+        blocks = [int(b) for b in rng.choice(np.arange(1, 48), n, replace=False)]
+        kv.reset_blocks(blocks)
+        released |= set(blocks)
+        for pos in (p for p in jax.tree_util.tree_leaves(kv.pools)
+                    if p.dtype.kind == "i"):
+            pos = np.asarray(pos)
+            rows = pos if pos.ndim == 2 else np.moveaxis(pos, 1, 0)
+            for b in range(48):
+                assert (rows[b] == (-1 if b in released else 7)).all()
+    grown = sum(f._cache_size() for f in fns) - sum(before)
+    shapes = {p.shape for p in jax.tree_util.tree_leaves(kv.pools)
+              if p.dtype.kind == "i"}
+    assert grown <= 7 * len(shapes)     # 1, 2, 4, ..., 64 blocks

@@ -83,7 +83,8 @@ def test_greedy_parity_continuous_vs_sequential(lm, wl):
         )
 
 
-@pytest.mark.parametrize("arch", ["gemma3-4b", "deepseek-v2-236b"])
+@pytest.mark.parametrize("arch", ["gemma3-4b", "deepseek-v2-236b",
+                                  "mellum2-12b-a2.5b"])
 def test_greedy_parity_other_mixer_kinds(arch):
     """The paged decode branches beyond plain GQA: gemma3 covers
     sliding-window layers (full-size pages + window *mask* replacing the
@@ -98,7 +99,7 @@ def test_greedy_parity_other_mixer_kinds(arch):
     workload = make_workload([(6, 4), (14, 5), (9, 3)], cfg.vocab_size,
                              seed=2)
     assert max(s + g for s, g in [(6, 4), (14, 5), (9, 3)]) > \
-        cfg.sliding_window or arch != "gemma3-4b"
+        cfg.sliding_window or arch == "deepseek-v2-236b"
     eng = ContinuousEngine(model, params, page_size=4, max_slots=2,
                            max_request_len=24)
     submit_all(eng, workload)
@@ -267,6 +268,15 @@ def test_greedy_parity_chunked_prefill(lm, chunk):
     eng = ContinuousEngine(model, params, page_size=4, max_slots=3,
                            max_request_len=40, prefill_chunk=chunk)
     submit_all(eng, workload)
+    waited = 0
+    while not eng.idle:
+        eng.step()
+        # a temp prefill cache exists only for the request whose chunks
+        # are running; the others admitted wait for theirs without one
+        live = [s.cache is not None for s in eng._prefilling.values()]
+        assert sum(live) <= 1 and (not live or live[0] or sum(live) == 0)
+        waited = max(waited, len(live) - sum(live))
+    assert waited > 0
     out = eng.drain()
     ref = run_sequential(model, params, workload,
                          cache_len=eng.gather_tokens)
@@ -284,6 +294,49 @@ def test_greedy_parity_chunked_prefill(lm, chunk):
     # and decode rows actually run alongside streaming chunks
     assert any(t["prefill_chunks"] == 1 and t["decode_rows"] > 0
                for t in eng.step_trace)
+
+
+def test_mellum_paged_decode_and_chunks_match_its_forward():
+    """A Mellum-shaped model (3:1 sliding/full attention, YaRN on the full
+    layers, routed experts in compact storage) served with 6-token chunks
+    and paged decode past its 16-token window: every greedy token is the
+    argmax of the model's own full forward over the served sequence, and
+    the engine counts E expert rows per token against top-k routed pairs."""
+    import dataclasses
+
+    cfg = reduce_config(get_config("mellum2-12b-a2.5b"))
+    cfg = apply_sparsity(cfg, pattern="rbgp4", sparsity=0.5,
+                         backend="xla_compact", min_dim=64)
+    assert cfg.rope_scaling is not None and cfg.sliding_window == 16
+    # the forward keeps every routed pair, as serving's full capacity does
+    cfg = cfg.with_(moe=dataclasses.replace(
+        cfg.moe, capacity_factor=cfg.moe.n_experts / cfg.moe.top_k))
+    model = LMModel(cfg)
+    params = model.init(jax.random.PRNGKey(4))
+    shapes = [(21, 9), (5, 14), (17, 6)]
+    workload = make_workload(shapes, cfg.vocab_size, seed=4)
+    eng = ContinuousEngine(model, params, page_size=4, max_slots=2,
+                           max_request_len=32, prefill_chunk=6)
+    submit_all(eng, workload)
+    out = eng.drain()
+    # one padded batch (causal: the padding after a sequence is unseen)
+    seqs = np.zeros((len(workload), 32), np.int32)
+    for i, r in enumerate(workload):
+        seq = np.concatenate([r["prompt"], out[r["rid"]]])
+        assert len(seq) > cfg.sliding_window
+        seqs[i, :len(seq)] = seq
+    logits = np.asarray(model.forward(params, {"tokens": jnp.asarray(seqs)})[0])
+    for i, r in enumerate(workload):
+        p, g = len(r["prompt"]), len(out[r["rid"]])
+        np.testing.assert_array_equal(
+            np.argmax(logits[i, p - 1:p + g - 1], -1), out[r["rid"]])
+    moe = cfg.moe
+    st = eng.stats
+    assert st["moe_routed"] > 0
+    assert st["moe_rows"] * moe.top_k == st["moe_routed"] * moe.n_experts
+    n_moe = sum(cfg.is_moe_layer(i) for i in range(cfg.n_layers))
+    tokens = st["decode_steps"] * 2 + st["prefill_chunks"] * 6
+    assert st["moe_routed"] == n_moe * moe.top_k * tokens
 
 
 # -- scheduler determinism ----------------------------------------------------------

@@ -150,3 +150,46 @@ def test_sharded_linear_compiles_on_four_chips(topo, ops):
     x_bytes = n * d.k * 2
     args = compiled.memory_analysis().argument_size_in_bytes
     assert args == x_bytes + w_bytes // 4, (args, x_bytes, w_bytes)
+
+
+# Mellum2-12B-A2.5B's projections (expert gate/up and down, wq, wk/wv,
+# wo) and DeepSeek-7B's 4096 x 11008: widths with odd factors, whose
+# slots are 32 or 64 lanes wide at sparsity 0.75
+ODD_WIDTHS = [(896, 2304), (2304, 896), (4096, 2304), (512, 2304),
+              (2304, 4096), (4096, 11008)]
+
+
+def _odd_op(shape):
+    from repro.core import RBGP4Layout, design_rbgp4
+    from repro.kernels import RBGP4Op
+
+    spec = design_rbgp4(*shape, 0.75)
+    assert spec.sparsity == 0.75
+    return RBGP4Op(RBGP4Layout(spec), interpret=False)
+
+
+@pytest.mark.parametrize("shape", ODD_WIDTHS)
+def test_odd_width_forward_and_grad_compile(one_chip, shape):
+    """Decode-sized forward with the fused epilogue, and the VJP at 512
+    tokens (SDDMM into narrow slots, dx on the transposed layout)."""
+    op = _odd_op(shape)
+    d = op.dims
+    S = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                           sharding=one_chip)
+    _compile(lambda x, w: op.linear(x, w, fuse="silu"),
+             S((16, d.k)), S((d.m, d.data_cols)))
+    grad = jax.grad(lambda w, x: op.linear(x, w).astype(jnp.float32).sum(),
+                    argnums=(0, 1))
+    text = _compile(grad, S((d.m, d.data_cols)), S((512, d.k)))
+    assert text.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("shape", ODD_WIDTHS[:5])
+def test_odd_width_stacked_experts_compile(one_chip, shape):
+    """The stacked forward over 64 experts, as the MoE layer runs it."""
+    op = _odd_op(shape)
+    d = op.dims
+    S = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                           sharding=one_chip)
+    _compile(lambda x, w: op.linear_stacked(x, w, fuse="silu"),
+             S((64, 16, d.k)), S((64, d.m, d.data_cols)))
